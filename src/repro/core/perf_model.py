@@ -1260,18 +1260,34 @@ class TpuGemmTiles:
         return self.est_flops / max(self.est_hbm_bytes, 1.0)
 
 
+# Scoped VMEM the flex_gemm kernel asks Mosaic for (``vmem_limit_bytes``)
+# and the budget its tiles are planned against: half of a TPU v5e
+# core's 128 MiB, leaving the rest to Mosaic's own scratch.
+TPU_VMEM_LIMIT_BYTES = 64 * 1024 * 1024
+
+
+def flex_gemm_vmem_bytes(bm: int, bk: int, bn: int,
+                         dtype_bytes: int = 2) -> int:
+    """VMEM one flex_gemm grid step holds (kernels/flex_gemm.py):
+    double-buffered A, B and output blocks, the f32 accumulator, and per
+    input element its f32 upcast, its masked copy and the i32 mask iota,
+    plus the f32 product of the step's dot."""
+    return (2 * (bm * bk + bk * bn) * dtype_bytes   # A, B blocks x2
+            + 2 * bm * bn * dtype_bytes              # output block x2
+            + bm * bn * 4                            # accumulator
+            + 3 * (bm * bk + bk * bn) * 4            # upcast, mask, iota
+            + bm * bn * 4)                           # dot product
+
+
 @lru_cache(maxsize=4096)
 def plan_tpu_gemm_tiles(M: int, K: int, N: int, dtype_bytes: int = 2,
-                        vmem_budget: int = 96 * 1024 * 1024,
+                        vmem_budget: int = TPU_VMEM_LIMIT_BYTES,
                         lane: int = 128, sublane: int = 8) -> TpuGemmTiles:
     """Choose MXU-aligned VMEM block shapes minimizing HBM traffic — the
     TPU instantiation of DORA's flexible memory management. Every block
     dim is a multiple of (sublane, lane) but *clamped to the operand*
     (dynamic bounds: remainders are masked in-kernel, never padded in
     HBM)."""
-    def clamp_align(x: int, a: int) -> int:
-        return min(round_up(x, a), round_up(x, a))
-
     best: TpuGemmTiles | None = None
     m_opts = sorted({min(round_up(M, sublane), v) for v in
                      (128, 256, 512, 1024, 2048)})
@@ -1282,9 +1298,8 @@ def plan_tpu_gemm_tiles(M: int, K: int, N: int, dtype_bytes: int = 2,
     for bm in m_opts:
         for bn in n_opts:
             for bk in k_opts:
-                # double-buffered working set
-                ws = 2 * (bm * bk + bk * bn) * dtype_bytes + bm * bn * 4
-                if ws > vmem_budget:
+                if flex_gemm_vmem_bytes(bm, bk, bn, dtype_bytes) \
+                        > vmem_budget:
                     continue
                 traffic = (ceil_div(N, bn) * M * K
                            + ceil_div(M, bm) * K * N

@@ -20,8 +20,6 @@ import numpy as np
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from repro.kernels._compat import COMPILER_PARAMS as _COMPILER_PARAMS
-
 _NEG_INF = -1e30
 
 
@@ -129,7 +127,7 @@ def flash_attention_pallas(q, k, v, *, causal: bool = True,
             ],
         ),
         out_shape=jax.ShapeDtypeStruct(q.shape, q.dtype),
-        compiler_params=_COMPILER_PARAMS(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "parallel",
                                  "arbitrary")),
         interpret=interpret,

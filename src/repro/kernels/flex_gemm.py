@@ -34,7 +34,7 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from repro.kernels._compat import COMPILER_PARAMS as _COMPILER_PARAMS
+from repro.core.perf_model import TPU_VMEM_LIMIT_BYTES
 
 EPILOGUES = ("none", "bias", "gelu", "relu", "relu2", "silu",
              "bias_gelu", "bias_relu", "bias_relu2", "bias_silu")
@@ -154,8 +154,9 @@ def flex_gemm_pallas(a: jax.Array, b: jax.Array,
             scratch_shapes=[pltpu.VMEM((block_m, block_n), jnp.float32)],
         ),
         out_shape=jax.ShapeDtypeStruct((M, N), out_dtype),
-        compiler_params=_COMPILER_PARAMS(
-            dimension_semantics=("parallel", "parallel", "arbitrary")),
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("parallel", "parallel", "arbitrary"),
+            vmem_limit_bytes=TPU_VMEM_LIMIT_BYTES),
         interpret=interpret,
     )(bounds, *operands)
     return out

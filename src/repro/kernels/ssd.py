@@ -25,8 +25,6 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from repro.kernels._compat import COMPILER_PARAMS as _COMPILER_PARAMS
-
 
 def _ssd_kernel(bounds_ref, x_ref, a_ref, b_ref, c_ref, y_ref, state_ref,
                 *, chunk: int):
@@ -39,24 +37,35 @@ def _ssd_kernel(bounds_ref, x_ref, a_ref, b_ref, c_ref, y_ref, state_ref,
     seq_len = bounds_ref[0]
     base = ci * chunk
     x = x_ref[0].astype(jnp.float32)        # (L, P)
-    a = a_ref[0].astype(jnp.float32)        # (L,) via (1, L) block
+    a = a_ref[0].astype(jnp.float32)        # (1, L): log-decays on lanes
     b = b_ref[0].astype(jnp.float32)        # (L, N)
     c = c_ref[0].astype(jnp.float32)        # (L, N)
 
     # mask the tail chunk: positions >= seq_len behave as identity
     # (decay 1 would corrupt the state; use a=-inf -> decay 0 for x,b and
     # simply zero x so the state stops changing, y masked on store side)
-    pos = base + jax.lax.iota(jnp.int32, chunk)
-    valid = pos < seq_len
-    a = jnp.where(valid, a, 0.0)
-    x = jnp.where(valid[:, None], x, 0.0)
-    b = jnp.where(valid[:, None], b, 0.0)
+    valid_row = (base + jax.lax.broadcasted_iota(jnp.int32, (1, chunk), 1)
+                 ) < seq_len
+    valid_col = (base + jax.lax.broadcasted_iota(jnp.int32, (chunk, 1), 0)
+                 ) < seq_len
+    a = jnp.where(valid_row, a, 0.0)
+    x = jnp.where(valid_col, x, 0.0)
+    b = jnp.where(valid_col, b, 0.0)
 
-    acs = jnp.cumsum(a)                      # (L,)
-    seg = acs[:, None] - acs[None, :]
+    # cumulative log-decay as a row and as a column, both by one matmul
+    # with the lower-triangular ones matrix (no 1-D cumsum or transpose)
     li = jax.lax.broadcasted_iota(jnp.int32, (chunk, chunk), 0)
     lj = jax.lax.broadcasted_iota(jnp.int32, (chunk, chunk), 1)
-    L = jnp.where(li >= lj, jnp.exp(seg), 0.0)
+    causal = li >= lj
+    tri = causal.astype(jnp.float32)
+    hi = jax.lax.Precision.HIGHEST
+    acs_col = jax.lax.dot_general(tri, a, (((1,), (1,)), ((), ())),
+                                  precision=hi,
+                                  preferred_element_type=jnp.float32)  # (L,1)
+    acs_row = jax.lax.dot_general(a, tri, (((1,), (1,)), ((), ())),
+                                  precision=hi,
+                                  preferred_element_type=jnp.float32)  # (1,L)
+    L = jnp.where(causal, jnp.exp(acs_col - acs_row), 0.0)
 
     cb = jax.lax.dot_general(c, b, (((1,), (1,)), ((), ())),
                              preferred_element_type=jnp.float32)  # (L, L)
@@ -66,11 +75,11 @@ def _ssd_kernel(bounds_ref, x_ref, a_ref, b_ref, c_ref, y_ref, state_ref,
     state = state_ref[...]                   # (P, N)
     y_off = jax.lax.dot_general(c, state, (((1,), (1,)), ((), ())),
                                 preferred_element_type=jnp.float32)
-    y_off = y_off * jnp.exp(acs)[:, None]
+    y_off = y_off * jnp.exp(acs_col)
 
-    a_total = acs[-1]
-    decay_in = jnp.exp(a_total - acs)        # (L,)
-    bx = jax.lax.dot_general(x, b * decay_in[:, None],
+    a_total = jnp.sum(a, axis=1, keepdims=True)      # (1, 1)
+    decay_in = jnp.exp(a_total - acs_col)            # (L, 1)
+    bx = jax.lax.dot_general(x, b * decay_in,
                              (((0,), (0,)), ((), ())),
                              preferred_element_type=jnp.float32)  # (P, N)
     state_ref[...] = jnp.exp(a_total) * state + bx
@@ -83,7 +92,8 @@ def ssd_pallas(x, a, b, c, *, chunk: int = 128, interpret: bool = False):
     """x: (BH, S, P), a: (BH, S), b/c: (BH, S, N) -> y: (BH, S, P).
 
     S is padded to a chunk multiple by the wrapper (ops.py) when needed;
-    the true length is masked in-kernel via scalar prefetch.
+    the true length is masked in-kernel via scalar prefetch. ``a`` is
+    carried as (BH, 1, S) so its block's last two dims are (full, chunk).
     """
     BH, S, P = x.shape
     N = b.shape[-1]
@@ -98,7 +108,7 @@ def ssd_pallas(x, a, b, c, *, chunk: int = 128, interpret: bool = False):
             grid=(BH, nc),
             in_specs=[
                 pl.BlockSpec((1, chunk, P), lambda i, j, bnds: (i, j, 0)),
-                pl.BlockSpec((1, chunk), lambda i, j, bnds: (i, j)),
+                pl.BlockSpec((1, 1, chunk), lambda i, j, bnds: (i, 0, j)),
                 pl.BlockSpec((1, chunk, N), lambda i, j, bnds: (i, j, 0)),
                 pl.BlockSpec((1, chunk, N), lambda i, j, bnds: (i, j, 0)),
             ],
@@ -107,7 +117,7 @@ def ssd_pallas(x, a, b, c, *, chunk: int = 128, interpret: bool = False):
             scratch_shapes=[pltpu.VMEM((P, N), jnp.float32)],
         ),
         out_shape=jax.ShapeDtypeStruct((BH, S, P), x.dtype),
-        compiler_params=_COMPILER_PARAMS(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "arbitrary")),
         interpret=interpret,
-    )(bounds, x, a, b, c)
+    )(bounds, x, a.reshape(BH, 1, S), b, c)

@@ -11,14 +11,10 @@ from __future__ import annotations
 import jax
 
 
-def _make_mesh(shape, axes):
-    # axis_types / AxisType only exist in newer jax; Auto is the default
-    # behaviour there, so older versions just omit the argument.
-    axis_type = getattr(jax.sharding, "AxisType", None)
-    if axis_type is not None:
-        return jax.make_mesh(shape, axes,
-                             axis_types=(axis_type.Auto,) * len(axes))
-    return jax.make_mesh(shape, axes)
+def _make_mesh(shape, axes, devices=None):
+    return jax.make_mesh(shape, axes,
+                         axis_types=(jax.sharding.AxisType.Auto,) * len(axes),
+                         devices=devices)
 
 
 def make_production_mesh(*, multi_pod: bool = False):
@@ -27,12 +23,14 @@ def make_production_mesh(*, multi_pod: bool = False):
     return _make_mesh(shape, axes)
 
 
-def make_local_mesh(model_axis: int = 1):
-    """Whatever devices exist, as (data, model) — used by examples,
-    tests, and single-host training."""
-    n = len(jax.devices())
-    assert n % model_axis == 0
-    return _make_mesh((n // model_axis, model_axis), ("data", "model"))
+def make_local_mesh(model_axis: int = 1, devices=None):
+    """``devices`` (all of them by default) as (data, model) — used by
+    the servers, examples, tests, and single-host training."""
+    devices = jax.devices() if devices is None else list(devices)
+    n = len(devices)
+    assert n % model_axis == 0, (n, model_axis)
+    return _make_mesh((n // model_axis, model_axis), ("data", "model"),
+                      devices)
 
 
 def make_pe_mesh(n_pes: int):

@@ -6,13 +6,19 @@ a real frontend), then decodes the whole batch in lock-step with one
 jitted decode step per token — the standard static-batch TPU serving
 shape. Sampling: greedy or temperature.
 
+The weights are kept in the config's compute dtype (bf16 at the
+published widths, f32 for the reduced configs) and placed with the
+mesh's parameter shardings, on the devices the activations run on.
+
 Run:  PYTHONPATH=src python -m repro.launch.serve --arch qwen3-4b \
-          --reduced --batch 4 --gen 32
+          --batch 4 --gen 32
+      (published widths; ``--reduced`` serves the tiny same-family model)
 """
 
 from __future__ import annotations
 
 import argparse
+import dataclasses
 import time
 from dataclasses import dataclass, field
 
@@ -21,9 +27,10 @@ import jax.numpy as jnp
 import numpy as np
 
 from repro.configs import get_config
+from repro.launch.compile_cache import enable_compile_cache
 from repro.launch.mesh import make_local_mesh
 from repro.models import lm
-from repro.parallel.sharding import make_rules, use_rules
+from repro.parallel.sharding import make_rules, params_shardings, use_rules
 
 
 @dataclass
@@ -35,31 +42,46 @@ class Request:
     tokens_out: list[int] = field(default_factory=list)
 
 
+def serving_steps(cfg, rules, max_len: int):
+    """The jitted prefill and decode steps a BatchServer runs."""
+
+    def _prefill(params, tokens):
+        with use_rules(rules):
+            return lm.prefill(cfg, params, tokens, max_len=max_len)
+
+    def _decode(params, cache, tok, pos):
+        with use_rules(rules):
+            return lm.decode_step(cfg, params, cache, tok, pos)
+
+    return jax.jit(_prefill), jax.jit(_decode, donate_argnums=(1,))
+
+
+def left_pad(requests: list[Request]) -> np.ndarray:
+    """(B, longest prompt) int32 batch, each prompt padded on the left."""
+    plen = max(len(r.prompt) for r in requests)
+    prompts = np.zeros((len(requests), plen), np.int32)
+    for i, r in enumerate(requests):
+        prompts[i, plen - len(r.prompt):] = r.prompt
+    return prompts
+
+
 class BatchServer:
     """Fixed-slot batched decoder (one model replica)."""
 
     def __init__(self, cfg, mesh, max_len: int = 256, seed: int = 0):
         assert not cfg.is_encdec, "serve.py drives decoder-only archs"
+        cfg = dataclasses.replace(cfg, param_dtype=cfg.compute_dtype)
         self.cfg = cfg
         self.mesh = mesh
         self.max_len = max_len
         self.rules = make_rules(cfg, mesh)
-        with use_rules(self.rules):
-            self.params, _ = jax.jit(
-                lambda k: lm.init(cfg, k)[0])(jax.random.PRNGKey(seed)), None
-        self.params = self.params[0] if isinstance(self.params, tuple) \
-            else self.params
-
-        def _prefill(params, tokens):
-            with use_rules(self.rules):
-                return lm.prefill(cfg, params, tokens, max_len=max_len)
-
-        def _decode(params, cache, tok, pos):
-            with use_rules(self.rules):
-                return lm.decode_step(cfg, params, cache, tok, pos)
-
-        self.prefill_fn = jax.jit(_prefill)
-        self.decode_fn = jax.jit(_decode, donate_argnums=(1,))
+        shapes, specs = lm.abstract_init(cfg)
+        self.param_shardings = params_shardings(self.rules, shapes, specs)
+        self.params = jax.jit(lambda k: lm.init(cfg, k)[0],
+                              out_shardings=self.param_shardings)(
+            jax.random.PRNGKey(seed))
+        self.prefill_fn, self.decode_fn = serving_steps(cfg, self.rules,
+                                                        max_len)
 
     def _sample(self, logits: jax.Array, temps: np.ndarray,
                 key) -> np.ndarray:
@@ -72,12 +94,11 @@ class BatchServer:
 
     def serve(self, requests: list[Request]) -> dict:
         B = len(requests)
-        plen = max(len(r.prompt) for r in requests)
-        prompts = np.zeros((B, plen), np.int32)
-        for i, r in enumerate(requests):
-            prompts[i, plen - len(r.prompt):] = r.prompt   # left pad
+        prompts = left_pad(requests)
+        plen = prompts.shape[1]
         t0 = time.perf_counter()
-        logits, cache = self.prefill_fn(self.params, jnp.asarray(prompts))
+        logits, cache = jax.block_until_ready(
+            self.prefill_fn(self.params, jnp.asarray(prompts)))
         t_prefill = time.perf_counter() - t0
 
         temps = np.array([r.temperature for r in requests], np.float32)
@@ -104,18 +125,25 @@ class BatchServer:
             "decode_s": t_decode,
             "decode_tok_per_s": B * ndec / t_decode if ndec else 0.0,
             "outputs": {r.id: r.tokens_out for r in requests},
+            "last_logits": logits,      # (B, V) logits of the last step
         }
 
 
-def main() -> None:
+def parse_args(argv: list[str] | None = None) -> argparse.Namespace:
     ap = argparse.ArgumentParser()
     ap.add_argument("--arch", default="qwen3-4b")
-    ap.add_argument("--reduced", action="store_true", default=True)
+    ap.add_argument("--reduced", action="store_true",
+                    help="serve the tiny same-family model instead of "
+                         "the published widths")
     ap.add_argument("--batch", type=int, default=4)
     ap.add_argument("--gen", type=int, default=32)
     ap.add_argument("--model-axis", type=int, default=1)
-    args = ap.parse_args()
+    return ap.parse_args(argv)
 
+
+def main() -> None:
+    args = parse_args()
+    enable_compile_cache()
     cfg = get_config(args.arch, reduced=args.reduced)
     mesh = make_local_mesh(model_axis=args.model_axis)
     server = BatchServer(cfg, mesh, max_len=128)

@@ -35,6 +35,7 @@ from repro import checkpoint as ckpt
 from repro.configs import get_config
 from repro.configs.shapes import ShapeSpec
 from repro.data import for_arch
+from repro.launch.compile_cache import enable_compile_cache
 from repro.launch.mesh import make_local_mesh
 from repro.launch.steps import make_train_step
 from repro.models import encdec, lm
@@ -165,6 +166,7 @@ def main() -> None:
     ap.add_argument("--ckpt-every", type=int, default=50)
     args = ap.parse_args()
 
+    enable_compile_cache()
     cfg = get_config(args.arch, reduced=args.reduced)
     mesh = make_local_mesh(model_axis=args.model_axis)
     shape = ShapeSpec("cli", args.seq, args.batch, "train")

@@ -165,7 +165,8 @@ def forward(cfg: ArchConfig, params, frames, tokens
     h, _ = jax.lax.scan(scan_fn, h, params["decoder"],
                         unroll=cfg.n_layers if cfg.scan_unroll else 1)
     h = L.apply_norm(cfg, params["final_norm"], h)
-    logits = (h @ params["lm_head"].astype(cd)).astype(jnp.float32)
+    logits = jnp.matmul(h, params["lm_head"].astype(cd),
+                        preferred_element_type=jnp.float32)
     return constrain(logits, "batch", None, "vocab"), jnp.float32(0.0)
 
 
@@ -226,7 +227,8 @@ def prefill(cfg: ArchConfig, params, frames, tokens,
     h, cache = jax.lax.scan(scan_fn, h, (params["decoder"], cache),
                             unroll=cfg.n_layers if cfg.scan_unroll else 1)
     h = L.apply_norm(cfg, params["final_norm"], h[:, -1:])
-    logits = (h @ params["lm_head"].astype(cd)).astype(jnp.float32)
+    logits = jnp.matmul(h, params["lm_head"].astype(cd),
+                        preferred_element_type=jnp.float32)
     return logits[:, 0], cache
 
 
@@ -263,5 +265,6 @@ def decode_step(cfg: ArchConfig, params, cache, tokens, pos
     h, cache = jax.lax.scan(scan_fn, h, (params["decoder"], cache),
                             unroll=cfg.n_layers if cfg.scan_unroll else 1)
     h = L.apply_norm(cfg, params["final_norm"], h)
-    logits = (h @ params["lm_head"].astype(cd)).astype(jnp.float32)
+    logits = jnp.matmul(h, params["lm_head"].astype(cd),
+                        preferred_element_type=jnp.float32)
     return logits[:, 0], cache

@@ -178,7 +178,8 @@ def forward(cfg: ArchConfig, params, tokens, positions=None
                                params["blocks"],
                                unroll=cfg.n_blocks if cfg.scan_unroll else 1)
     h = L.apply_norm(cfg, params["final_norm"], h)
-    logits = (h @ params["lm_head"].astype(cd)).astype(jnp.float32)
+    logits = jnp.matmul(h, params["lm_head"].astype(cd),
+                        preferred_element_type=jnp.float32)
     logits = constrain(logits, "batch", None, "vocab")
     return logits, aux
 
@@ -279,7 +280,8 @@ def prefill(cfg: ArchConfig, params, tokens, max_len: int | None = None
     h, new_cache = jax.lax.scan(scan_fn, h, (params["blocks"], cache),
                                 unroll=cfg.n_blocks if cfg.scan_unroll else 1)
     h = L.apply_norm(cfg, params["final_norm"], h[:, -1:])
-    logits = (h @ params["lm_head"].astype(cd)).astype(jnp.float32)
+    logits = jnp.matmul(h, params["lm_head"].astype(cd),
+                        preferred_element_type=jnp.float32)
     return logits[:, 0], new_cache
 
 
@@ -320,5 +322,6 @@ def decode_step(cfg: ArchConfig, params, cache, tokens, pos
     h, new_cache = jax.lax.scan(scan_fn, h, (params["blocks"], cache),
                                 unroll=cfg.n_blocks if cfg.scan_unroll else 1)
     h = L.apply_norm(cfg, params["final_norm"], h)
-    logits = (h @ params["lm_head"].astype(cd)).astype(jnp.float32)
+    logits = jnp.matmul(h, params["lm_head"].astype(cd),
+                        preferred_element_type=jnp.float32)
     return logits[:, 0], new_cache
