@@ -10,6 +10,10 @@ The weights are kept in the config's compute dtype (bf16 at the
 published widths, f32 for the reduced configs) and placed with the
 mesh's parameter shardings, on the devices the activations run on.
 
+``serve`` marks its phases with ``jax.profiler.TraceAnnotation`` host
+spans (``SPANS``; inert unless a profiler trace runs) and adds up the
+work it did in ``BatchServer.counters``.
+
 Run:  PYTHONPATH=src python -m repro.launch.serve --arch qwen3-4b \
           --batch 4 --gen 32
       (published widths; ``--reduced`` serves the tiny same-family model)
@@ -31,6 +35,25 @@ from repro.launch.compile_cache import enable_compile_cache
 from repro.launch.mesh import make_local_mesh
 from repro.models import lm
 from repro.parallel.sharding import make_rules, params_shardings, use_rules
+
+
+# host spans of ``BatchServer.serve``, on the profiler's clock
+SPAN_PREFILL = "serve.prefill"   # prompt transfer, prefill, its sync
+SPAN_RNG = "serve.rng"           # sampling keys
+SPAN_DECODE = "serve.decode"     # token transfer and decode dispatch
+SPAN_SAMPLE = "serve.sample"     # argmax and the host sync on it
+SPAN_COLLECT = "serve.collect"   # appending tokens to the requests
+SPANS = (SPAN_PREFILL, SPAN_RNG, SPAN_DECODE, SPAN_SAMPLE, SPAN_COLLECT)
+
+
+@dataclass
+class ServeCounters:
+    """Cumulative counts of the work ``BatchServer.serve`` did."""
+    prompt_tokens: int = 0       # true prompt lengths
+    prefill_positions: int = 0   # rows x padded width
+    decode_steps: int = 0
+    slots: int = 0               # rows x the wave's longest max_new
+    tokens_kept: int = 0         # tokens appended to a request
 
 
 @dataclass
@@ -82,6 +105,7 @@ class BatchServer:
             jax.random.PRNGKey(seed))
         self.prefill_fn, self.decode_fn = serving_steps(cfg, self.rules,
                                                         max_len)
+        self.counters = ServeCounters()
 
     def _sample(self, logits: jax.Array, temps: np.ndarray,
                 key) -> np.ndarray:
@@ -93,37 +117,52 @@ class BatchServer:
         return np.where(temps > 0, noisy, greedy)
 
     def serve(self, requests: list[Request]) -> dict:
+        Span = jax.profiler.TraceAnnotation
         B = len(requests)
         prompts = left_pad(requests)
         plen = prompts.shape[1]
         t0 = time.perf_counter()
-        logits, cache = jax.block_until_ready(
-            self.prefill_fn(self.params, jnp.asarray(prompts)))
+        with Span(SPAN_PREFILL):
+            logits, cache = jax.block_until_ready(
+                self.prefill_fn(self.params, jnp.asarray(prompts)))
         t_prefill = time.perf_counter() - t0
 
         temps = np.array([r.temperature for r in requests], np.float32)
-        key = jax.random.PRNGKey(0)
+        with Span(SPAN_RNG):
+            key = jax.random.PRNGKey(0)
         max_new = max(r.max_new for r in requests)
-        tok = self._sample(logits, temps, key)
-        for i, r in enumerate(requests):
-            r.tokens_out.append(int(tok[i]))
-        t0 = time.perf_counter()
-        ndec = 0
-        for t in range(1, max_new):
-            key, sub = jax.random.split(key)
-            logits, cache = self.decode_fn(
-                self.params, cache, jnp.asarray(tok[:, None], jnp.int32),
-                jnp.int32(plen + t - 1))
-            tok = self._sample(logits, temps, sub)
-            ndec += 1
+        with Span(SPAN_SAMPLE):
+            tok = self._sample(logits, temps, key)
+        with Span(SPAN_COLLECT):
             for i, r in enumerate(requests):
-                if len(r.tokens_out) < r.max_new:
-                    r.tokens_out.append(int(tok[i]))
+                r.tokens_out.append(int(tok[i]))
+        kept = B
+        t0 = time.perf_counter()
+        for t in range(1, max_new):
+            with Span(SPAN_RNG):
+                key, sub = jax.random.split(key)
+            with Span(SPAN_DECODE):
+                logits, cache = self.decode_fn(
+                    self.params, cache, jnp.asarray(tok[:, None], jnp.int32),
+                    jnp.int32(plen + t - 1))
+            with Span(SPAN_SAMPLE):
+                tok = self._sample(logits, temps, sub)
+            with Span(SPAN_COLLECT):
+                for i, r in enumerate(requests):
+                    if len(r.tokens_out) < r.max_new:
+                        r.tokens_out.append(int(tok[i]))
+                        kept += 1
         t_decode = time.perf_counter() - t0
+
+        c = self.counters
+        c.prompt_tokens += sum(len(r.prompt) for r in requests)
+        c.prefill_positions += B * plen
+        c.decode_steps += max(max_new - 1, 0)
+        c.slots += B * max_new
+        c.tokens_kept += kept
         return {
             "prefill_s": t_prefill,
             "decode_s": t_decode,
-            "decode_tok_per_s": B * ndec / t_decode if ndec else 0.0,
             "outputs": {r.id: r.tokens_out for r in requests},
             "last_logits": logits,      # (B, V) logits of the last step
         }
@@ -153,8 +192,10 @@ def main() -> None:
                     max_new=args.gen, temperature=0.7 * (i % 2))
             for i in range(args.batch)]
     stats = server.serve(reqs)
-    print(f"prefill {stats['prefill_s']:.3f}s, "
-          f"decode {stats['decode_tok_per_s']:.1f} tok/s")
+    gen = sum(len(t) for t in stats["outputs"].values())
+    print(f"prefill {stats['prefill_s']:.3f}s, decode {stats['decode_s']:.3f}s"
+          f", {gen / (stats['prefill_s'] + stats['decode_s']):.1f} "
+          f"generated tok/s")
     for rid, toks in stats["outputs"].items():
         print(f"  req {rid}: {toks[:12]}...")
 

@@ -2,10 +2,16 @@
 MoE FFN. Pure-functional JAX; every init returns ``(params, specs)``
 where specs mirror the params tree with logical-axis tuples consumed by
 repro.parallel.sharding.
+
+Each layer function traces under a ``jax.named_scope`` (``SCOPES``), so
+every operation of a compiled step carries its layer in the HLO
+``op_name`` metadata ("jit(_decode)/while/body/closed_call/attn/...")
+and a profile can be split by layer. Scopes change metadata only.
 """
 
 from __future__ import annotations
 
+import functools
 import math
 from typing import Any
 
@@ -17,6 +23,21 @@ from repro.kernels import ref
 from repro.parallel.sharding import constrain
 
 Tree = Any
+
+# the named scopes of the model's layers; the embedding gather and the
+# LM head (final norm and head matmul) are scoped where ``lm`` runs them
+SCOPES = ("embed", "norm", "attn", "mlp", "moe", "ssm", "lm_head")
+
+
+def scoped(name: str):
+    """Decorator: trace the function under ``jax.named_scope(name)``."""
+    def deco(fn):
+        @functools.wraps(fn)
+        def wrapped(*args, **kwargs):
+            with jax.named_scope(name):
+                return fn(*args, **kwargs)
+        return wrapped
+    return deco
 
 
 def _init(key, shape, scale=None, dtype=jnp.float32):
@@ -34,6 +55,7 @@ def init_norm(cfg, d=None):
     return ({"scale": jnp.ones((d,))}, {"scale": ("embed_act",)})
 
 
+@scoped("norm")
 def apply_norm(cfg, p, x):
     if cfg.norm_kind == "layernorm":
         return ref.layernorm_rows(x, p["scale"], p["bias"])
@@ -128,6 +150,7 @@ def _project_qkv(cfg, p, x, positions, rope: bool):
     return q, k, v
 
 
+@scoped("attn")
 def attention_fwd(cfg, p, x, positions, *, causal: bool = True,
                   kv_override=None):
     """Full-sequence attention (training / prefill).
@@ -174,6 +197,7 @@ def encode_kv(cfg, p, enc_out):
     return k, v
 
 
+@scoped("attn")
 def attention_decode(cfg, p, x, cache_k, cache_v, pos, *,
                      cross: bool = False, kv_len=None, rope: bool = True):
     """Single-token decode. x: (B, 1, D); cache_k/v: (B, Hkv, Smax, D);
@@ -232,6 +256,7 @@ def init_mlp(cfg, key):
             {"w_up": ("embed", "mlp"), "w_down": ("mlp", "embed")})
 
 
+@scoped("mlp")
 def mlp_fwd(cfg, p, x):
     if cfg.mlp_kind == "swiglu":
         h = jax.nn.silu(x @ p["w_gate"].astype(x.dtype)) \
@@ -268,6 +293,7 @@ def init_moe(cfg, key):
     return p, s
 
 
+@scoped("moe")
 def moe_fwd(cfg, p, x, group_size: int = 1024):
     """Capacity-bounded top-k MoE with deterministic in-group dispatch
     (GShard-style dense einsum dispatch — GSPMD/EP friendly: the

@@ -154,8 +154,9 @@ def forward(cfg: ArchConfig, params, tokens, positions=None
             ) -> tuple[jax.Array, jax.Array]:
     """tokens: (B, S) int32 -> logits (B, S, V) in f32, aux loss."""
     cd = jnp.dtype(cfg.compute_dtype)
-    h = params["embed"].astype(cd)[tokens]
-    h = constrain(h, "batch", None, "embed_act")
+    with jax.named_scope("embed"):
+        h = params["embed"].astype(cd)[tokens]
+        h = constrain(h, "batch", None, "embed_act")
     if positions is None:
         positions = _positions_for(cfg, tokens)
 
@@ -177,10 +178,11 @@ def forward(cfg: ArchConfig, params, tokens, positions=None
     (h, aux), _ = jax.lax.scan(scan_fn, (h, jnp.float32(0.0)),
                                params["blocks"],
                                unroll=cfg.n_blocks if cfg.scan_unroll else 1)
-    h = L.apply_norm(cfg, params["final_norm"], h)
-    logits = jnp.matmul(h, params["lm_head"].astype(cd),
-                        preferred_element_type=jnp.float32)
-    logits = constrain(logits, "batch", None, "vocab")
+    with jax.named_scope("lm_head"):
+        h = L.apply_norm(cfg, params["final_norm"], h)
+        logits = jnp.matmul(h, params["lm_head"].astype(cd),
+                            preferred_element_type=jnp.float32)
+        logits = constrain(logits, "batch", None, "vocab")
     return logits, aux
 
 
@@ -240,8 +242,9 @@ def prefill(cfg: ArchConfig, params, tokens, max_len: int | None = None
     B, Sp = tokens.shape
     max_len = max_len or Sp
     cd = jnp.dtype(cfg.compute_dtype)
-    h = params["embed"].astype(cd)[tokens]
-    h = constrain(h, "batch", None, "embed_act")
+    with jax.named_scope("embed"):
+        h = params["embed"].astype(cd)[tokens]
+        h = constrain(h, "batch", None, "embed_act")
     positions = _positions_for(cfg, tokens)
     cache = init_cache(cfg, B, max_len)
 
@@ -255,13 +258,16 @@ def prefill(cfg: ArchConfig, params, tokens, max_len: int | None = None
             if pat.mixer == "attn":
                 mix, (k, v) = L.attention_fwd(cfg, p["attn"], hn, positions,
                                               causal=True)
-                if cfg.kv_cache_repeat > 1:
-                    k = jnp.repeat(k, cfg.kv_cache_repeat, axis=1)
-                    v = jnp.repeat(v, cfg.kv_cache_repeat, axis=1)
-                ck = jax.lax.dynamic_update_slice(
-                    cache_slice[f"pos{pi}"]["k"], k.astype(cd), (0, 0, 0, 0))
-                cv = jax.lax.dynamic_update_slice(
-                    cache_slice[f"pos{pi}"]["v"], v.astype(cd), (0, 0, 0, 0))
+                with jax.named_scope("attn"):
+                    if cfg.kv_cache_repeat > 1:
+                        k = jnp.repeat(k, cfg.kv_cache_repeat, axis=1)
+                        v = jnp.repeat(v, cfg.kv_cache_repeat, axis=1)
+                    ck = jax.lax.dynamic_update_slice(
+                        cache_slice[f"pos{pi}"]["k"], k.astype(cd),
+                        (0, 0, 0, 0))
+                    cv = jax.lax.dynamic_update_slice(
+                        cache_slice[f"pos{pi}"]["v"], v.astype(cd),
+                        (0, 0, 0, 0))
                 new_slice[f"pos{pi}"] = {"k": ck, "v": cv}
             else:
                 mix, state, conv = S.ssm_fwd_with_cache(cfg, p["ssm"], hn)
@@ -279,9 +285,10 @@ def prefill(cfg: ArchConfig, params, tokens, max_len: int | None = None
 
     h, new_cache = jax.lax.scan(scan_fn, h, (params["blocks"], cache),
                                 unroll=cfg.n_blocks if cfg.scan_unroll else 1)
-    h = L.apply_norm(cfg, params["final_norm"], h[:, -1:])
-    logits = jnp.matmul(h, params["lm_head"].astype(cd),
-                        preferred_element_type=jnp.float32)
+    with jax.named_scope("lm_head"):
+        h = L.apply_norm(cfg, params["final_norm"], h[:, -1:])
+        logits = jnp.matmul(h, params["lm_head"].astype(cd),
+                            preferred_element_type=jnp.float32)
     return logits[:, 0], new_cache
 
 
@@ -290,7 +297,8 @@ def decode_step(cfg: ArchConfig, params, cache, tokens, pos
     """One decode step. tokens: (B, 1) int32; pos: scalar int32 (number
     of tokens already in the cache). Returns (logits (B, V), cache)."""
     cd = jnp.dtype(cfg.compute_dtype)
-    h = params["embed"].astype(cd)[tokens]
+    with jax.named_scope("embed"):
+        h = params["embed"].astype(cd)[tokens]
 
     def scan_fn(carry, xs):
         x = carry
@@ -321,7 +329,8 @@ def decode_step(cfg: ArchConfig, params, cache, tokens, pos
 
     h, new_cache = jax.lax.scan(scan_fn, h, (params["blocks"], cache),
                                 unroll=cfg.n_blocks if cfg.scan_unroll else 1)
-    h = L.apply_norm(cfg, params["final_norm"], h)
-    logits = jnp.matmul(h, params["lm_head"].astype(cd),
-                        preferred_element_type=jnp.float32)
+    with jax.named_scope("lm_head"):
+        h = L.apply_norm(cfg, params["final_norm"], h)
+        logits = jnp.matmul(h, params["lm_head"].astype(cd),
+                            preferred_element_type=jnp.float32)
     return logits[:, 0], new_cache

@@ -20,6 +20,7 @@ import jax
 import jax.numpy as jnp
 
 from repro.kernels import ops, ref
+from repro.models.layers import scoped
 from repro.parallel.sharding import constrain
 
 
@@ -74,6 +75,7 @@ def _causal_conv(xbc, conv_w, conv_b, prev=None):
     return out + conv_b[None, None]
 
 
+@scoped("ssm")
 def ssm_fwd(cfg, p, x):
     """Training path. x: (B, S, D) -> (B, S, D)."""
     B, S, D = x.shape
@@ -104,6 +106,7 @@ def ssm_fwd(cfg, p, x):
     return constrain(out, "batch", None, "embed_act")
 
 
+@scoped("ssm")
 def ssm_fwd_with_cache(cfg, p, x):
     """Prefill returning decode caches (conv window + SSD state)."""
     B, S, D = x.shape
@@ -132,6 +135,7 @@ def ssm_fwd_with_cache(cfg, p, x):
     return out, state.astype(jnp.float32), conv_window
 
 
+@scoped("ssm")
 def ssm_decode(cfg, p, x, conv_window, state):
     """Single-token decode. x: (B, 1, D); conv_window: (B, K-1, conv_dim);
     state: (B, nh, ph, N). Returns (out, conv_window, state)."""

@@ -11,6 +11,7 @@ different tests. Keep every such compile in this one file.
 
 import dataclasses
 import os
+import re
 
 import jax
 import jax.numpy as jnp
@@ -153,12 +154,28 @@ def test_qwen3_prefill_compiles_and_fits(no_compile_cache, qwen3_serving):
     assert _device_bytes(compiled) < HBM_BYTES
 
 
-def test_qwen3_decode_compiles_and_fits(no_compile_cache, qwen3_serving):
+@pytest.fixture(scope="module")
+def qwen3_decode(no_compile_cache, qwen3_serving):
+    """The qwen3-4b decode step compiled for one described chip."""
     from repro.models import lm
 
     cfg, rep, params, _, decode = qwen3_serving
     cache_shapes = jax.eval_shape(lambda: lm.init_cache(cfg, B, MAX_LEN))
     cache = jax.tree.map(lambda s: _sds(rep, s.shape, s.dtype), cache_shapes)
-    compiled = decode.lower(params, cache, _sds(rep, (B, 1), jnp.int32),
-                            _sds(rep, (), jnp.int32)).compile()
-    assert _device_bytes(compiled) < HBM_BYTES
+    return decode.lower(params, cache, _sds(rep, (B, 1), jnp.int32),
+                        _sds(rep, (), jnp.int32)).compile()
+
+
+def test_qwen3_decode_compiles_and_fits(qwen3_decode):
+    assert _device_bytes(qwen3_decode) < HBM_BYTES
+
+
+def test_qwen3_decode_fusions_keep_layer_scopes(qwen3_decode):
+    """The chip's compiler keeps the model's named scopes on the fused
+    operations, where a profile of the chip finds them."""
+    scopes = set()
+    for line in qwen3_decode.as_text().splitlines():
+        m = re.search(r"= \S+ fusion\(.*op_name=\"([^\"]*)\"", line)
+        if m:
+            scopes |= set(m.group(1).split("/"))
+    assert {"attn", "mlp"} <= scopes
