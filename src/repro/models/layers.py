@@ -200,8 +200,15 @@ def encode_kv(cfg, p, enc_out):
 @scoped("attn")
 def attention_decode(cfg, p, x, cache_k, cache_v, pos, *,
                      cross: bool = False, kv_len=None, rope: bool = True):
-    """Single-token decode. x: (B, 1, D); cache_k/v: (B, Hkv, Smax, D);
+    """Single-token decode. x: (B, 1, D); cache_k/v: (B, Hc, Smax, D);
     pos: scalar int32 — current position (tokens already in cache).
+
+    q is reshaped into the cache's head groups, (B, Hc, Hq // Hc, D),
+    and contracted against the cache as stored: no upcast or repeated
+    copy of the cache is made. Hc is read off the cache, so GQA, MHA
+    and a cache holding repeated KV heads take the same path. The
+    products accumulate in float32, and the scale, mask and softmax run
+    on the float32 logits.
 
     For cross-attention the cache holds encoder KV and is not updated.
     Returns (out, cache_k, cache_v).
@@ -230,12 +237,18 @@ def attention_decode(cfg, p, x, cache_k, cache_v, pos, *,
         if cfg.qk_norm:
             q = ref.rmsnorm_rows(q, p["q_norm"])
         valid = cache_k.shape[2] if kv_len is None else kv_len
-    q_t = q.transpose(0, 2, 1, 3)
-    lens = jnp.full((B,), valid, dtype=jnp.int32)
-    out = ref.mha_attention(q_t, cache_k.astype(q_t.dtype),
-                            cache_v.astype(q_t.dtype),
-                            causal=False, kv_len=lens)
-    out = out.transpose(0, 2, 1, 3).reshape(B, 1, cfg.q_dim)
+    _, Hc, Smax, D = cache_k.shape
+    assert cfg.n_heads % Hc == 0, (cfg.n_heads, Hc)
+    qg = q.reshape(B, Hc, cfg.n_heads // Hc, D)
+    logits = jnp.einsum("bkgd,bktd->bkgt", qg, cache_k,
+                        preferred_element_type=jnp.float32)
+    logits = logits * (1.0 / math.sqrt(D))
+    lens = jnp.broadcast_to(valid, (B,))[:, None, None, None]
+    logits = jnp.where(jnp.arange(Smax) < lens, logits, -jnp.inf)
+    probs = jax.nn.softmax(logits, axis=-1)
+    out = jnp.einsum("bkgt,bktd->bkgd", probs, cache_v,
+                     preferred_element_type=jnp.float32)
+    out = out.astype(x.dtype).reshape(B, 1, cfg.q_dim)
     out = out @ p["wo"].astype(x.dtype)
     return out, cache_k, cache_v
 

@@ -118,6 +118,61 @@ def test_whisper_decode_consistency():
     assert max(errs) < 2e-3
 
 
+# (n_heads, n_kv_heads, kv_cache_repeat): query group 1 (MHA), 4, 6, and
+# a cache holding each KV head twice (4 cache heads, group 2)
+DECODE_HEADS = [(4, 4, 1), (8, 2, 1), (12, 2, 1), (8, 2, 2)]
+DECODE_TOL = {jnp.float32: 1e-5, jnp.bfloat16: 2e-2}
+MAX_LEN = 16
+
+
+@pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16])
+@pytest.mark.parametrize("valid", [1, MAX_LEN // 2 + 1, MAX_LEN])
+@pytest.mark.parametrize("cross", [False, True])
+@pytest.mark.parametrize("heads", DECODE_HEADS)
+def test_attention_decode_matches_oracle(heads, cross, valid, dtype):
+    """Decode attention contracted by query group over the cache as
+    stored equals the oracle, which upcasts and repeats the cache, on
+    the same q, cache and valid length (``valid`` tokens attended: the
+    position written is ``valid - 1``, or ``kv_len`` on the cross
+    path)."""
+    from repro.kernels import ref
+    from repro.models import layers as L
+    n_heads, n_kv, rep = heads
+    cfg = dataclasses.replace(get_config("qwen3-4b", reduced=True),
+                              n_heads=n_heads, n_kv_heads=n_kv,
+                              kv_cache_repeat=rep)
+    B, Hc, D = 2, n_kv * rep, cfg.head_dim
+    p, _ = L.init_attention(cfg, jax.random.PRNGKey(6))
+    rng = np.random.default_rng(7)
+    x = jnp.asarray(rng.standard_normal((B, 1, cfg.d_model)), dtype)
+    cache_k, cache_v = (
+        jnp.asarray(rng.standard_normal((B, Hc, MAX_LEN, D)), dtype)
+        for _ in range(2))
+    pos = jnp.int32(valid - 1)
+    out, ck, cv = L.attention_decode(cfg, p, x, cache_k, cache_v, pos,
+                                     cross=cross,
+                                     kv_len=valid if cross else None)
+
+    if cross:
+        q, _, _ = L._project_qkv(cfg, p, x, None, rope=False)
+        assert ck is cache_k and cv is cache_v
+    else:
+        q, _, _ = L._project_qkv(cfg, p, x, jnp.full((B, 1), pos),
+                                 rope=True)
+        # the new token's K/V landed at ``pos``, nothing else moved
+        keep = jnp.arange(MAX_LEN)[:, None] != pos
+        assert bool(jnp.all(jnp.where(keep, ck == cache_k, True)))
+    want = ref.mha_attention(q.transpose(0, 2, 1, 3), ck, cv, causal=False,
+                             kv_len=jnp.full((B,), valid, jnp.int32))
+    want = want.transpose(0, 2, 1, 3).reshape(B, 1, cfg.q_dim)
+    want = want.astype(dtype) @ p["wo"].astype(dtype)
+    assert out.dtype == dtype
+    tol = DECODE_TOL[dtype]
+    np.testing.assert_allclose(np.asarray(out, np.float32),
+                               np.asarray(want, np.float32),
+                               rtol=tol, atol=tol)
+
+
 def test_m_rope_reduces_to_rope_for_text():
     """qwen2-vl M-RoPE with equal position channels == standard RoPE."""
     from repro.models.layers import apply_rope

@@ -170,6 +170,18 @@ def test_qwen3_decode_compiles_and_fits(qwen3_decode):
     assert _device_bytes(qwen3_decode) < HBM_BYTES
 
 
+def test_qwen3_decode_never_widens_the_cache(qwen3_decode):
+    """Decode attention contracts the bf16 cache by query group: no
+    float32 copy of one layer's cache, nor of one repeated to the query
+    heads, is made, and the temporaries stay below the 3.02e9 B that
+    such copies took (2.42e9 B without them)."""
+    text = qwen3_decode.as_text()
+    for shape in ("f32[8,8,4,2048,128]", "f32[8,8,2048,128]",
+                  "f32[8,32,2048,128]"):
+        assert shape not in text
+    assert qwen3_decode.memory_analysis().temp_size_in_bytes < 2.6e9
+
+
 def test_qwen3_decode_fusions_keep_layer_scopes(qwen3_decode):
     """The chip's compiler keeps the model's named scopes on the fused
     operations, where a profile of the chip finds them."""
