@@ -40,6 +40,11 @@ class ArchConfig:
     # mlp
     mlp_kind: str = "swiglu"  # swiglu | gelu | relu2
     norm_kind: str = "rmsnorm"  # rmsnorm | layernorm
+    rms_norm_eps: float = 1e-6  # every RMSNorm: blocks, final, qk, SSM gate
+
+    # embedding and residual stream
+    tie_embeddings: bool = False    # LM head is the embedding, transposed
+    residual_in_fp32: bool = False  # the layer scan carries h in float32
 
     # block pattern (repeats n_layers // pattern_len times)
     pattern: tuple[LayerPattern, ...] = (LayerPattern(),)
@@ -135,10 +140,11 @@ class ArchConfig:
         """Total parameters (embeddings + blocks + head)."""
         d, V = self.d_model, self.vocab_size
         total = V * d              # token embedding
-        total += V * d             # lm head (untied)
+        if not self.tie_embeddings:
+            total += V * d         # lm head
         total += d                 # final norm
         for p in self.pattern:
-            per = 2 * d            # two norms
+            per = d if p.ffn == "none" else 2 * d   # pre-mixer, pre-FFN norms
             if p.mixer == "attn":
                 per += d * self.q_dim + 2 * d * self.kv_dim \
                     + self.q_dim * d
@@ -150,15 +156,17 @@ class ArchConfig:
                 din = self.ssm_inner
                 nh, ns = self.ssm_heads, self.ssm_state
                 proj_in = 2 * din + 2 * self.ssm_groups * ns + nh
+                conv_dim = din + 2 * self.ssm_groups * ns
                 per += d * proj_in                 # in_proj
-                per += self.ssm_conv_width * (din + 2 * self.ssm_groups * ns)
+                per += (self.ssm_conv_width + 1) * conv_dim   # conv w, bias
                 per += nh * 3                      # A_log, D, dt_bias
+                per += din                         # gated norm
                 per += din * d                     # out_proj
             if p.ffn == "moe":
                 per += d * self.n_experts          # router
                 mults = 3 if self.mlp_kind == "swiglu" else 2
                 per += self.n_experts * mults * d * self.d_ff
-            else:
+            elif p.ffn == "dense":
                 mults = 3 if self.mlp_kind == "swiglu" else 2
                 per += mults * d * self.d_ff
             total += per * self.n_blocks

@@ -25,8 +25,11 @@ from repro.parallel.sharding import constrain
 Tree = Any
 
 # the named scopes of the model's layers; the embedding gather and the
-# LM head (final norm and head matmul) are scoped where ``lm`` runs them
-SCOPES = ("embed", "norm", "attn", "mlp", "moe", "ssm", "lm_head")
+# LM head (final norm and head matmul) are scoped where ``lm`` runs them.
+# ``ssm_scan`` (the SSD recurrence and its skip term) is a sibling of
+# ``ssm`` (projections, conv, gated norm), not nested in it.
+SCOPES = ("embed", "norm", "attn", "mlp", "moe", "ssm", "ssm_scan",
+          "lm_head")
 
 
 def scoped(name: str):
@@ -57,9 +60,13 @@ def init_norm(cfg, d=None):
 
 @scoped("norm")
 def apply_norm(cfg, p, x):
+    """The block or final norm of ``x``, in the compute dtype (``x`` may
+    be the float32 residual stream)."""
     if cfg.norm_kind == "layernorm":
-        return ref.layernorm_rows(x, p["scale"], p["bias"])
-    return ref.rmsnorm_rows(x, p["scale"])
+        y = ref.layernorm_rows(x, p["scale"], p["bias"])
+    else:
+        y = ref.rmsnorm_rows(x, p["scale"], cfg.rms_norm_eps)
+    return y.astype(cfg.compute_dtype)
 
 
 # -------------------------------------------------------------------- rope
@@ -141,8 +148,8 @@ def _project_qkv(cfg, p, x, positions, rope: bool):
     k = k.reshape(B, S, cfg.n_kv_heads, cfg.head_dim)
     v = v.reshape(B, S, cfg.n_kv_heads, cfg.head_dim)
     if cfg.qk_norm:
-        q = ref.rmsnorm_rows(q, p["q_norm"])
-        k = ref.rmsnorm_rows(k, p["k_norm"])
+        q = ref.rmsnorm_rows(q, p["q_norm"], cfg.rms_norm_eps)
+        k = ref.rmsnorm_rows(k, p["k_norm"], cfg.rms_norm_eps)
     if rope and positions is not None:
         sections = cfg.m_rope_sections if cfg.m_rope else None
         q = apply_rope(q, positions, cfg.rope_theta, sections)
@@ -169,7 +176,7 @@ def attention_fwd(cfg, p, x, positions, *, causal: bool = True,
             q = q + p["bq"].astype(x.dtype)
         q = q.reshape(B, S, cfg.n_heads, cfg.head_dim)
         if cfg.qk_norm:
-            q = ref.rmsnorm_rows(q, p["q_norm"])
+            q = ref.rmsnorm_rows(q, p["q_norm"], cfg.rms_norm_eps)
         k_t, v_t = kv_override
     q_t = q.transpose(0, 2, 1, 3)
     q_t = constrain(q_t, "batch_attn", "heads", None, None)
@@ -235,7 +242,7 @@ def attention_decode(cfg, p, x, cache_k, cache_v, pos, *,
             q = q + p["bq"].astype(x.dtype)
         q = q.reshape(B, 1, cfg.n_heads, cfg.head_dim)
         if cfg.qk_norm:
-            q = ref.rmsnorm_rows(q, p["q_norm"])
+            q = ref.rmsnorm_rows(q, p["q_norm"], cfg.rms_norm_eps)
         valid = cache_k.shape[2] if kv_len is None else kv_len
     _, Hc, Smax, D = cache_k.shape
     assert cfg.n_heads % Hc == 0, (cfg.n_heads, Hc)

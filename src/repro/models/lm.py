@@ -60,14 +60,11 @@ def _stack_specs(spec: Tree) -> Tree:
 def init(cfg: ArchConfig, key) -> tuple[Tree, Tree]:
     keys = jax.random.split(key, 4)
     V, D = cfg.vocab_size, cfg.d_model
-    params: dict = {
-        "embed": jax.random.normal(keys[0], (V, D)) * 0.02,
-        "lm_head": jax.random.normal(keys[1], (D, V)) / math.sqrt(D),
-    }
-    specs: dict = {
-        "embed": ("vocab", "embed"),
-        "lm_head": ("embed", "vocab"),
-    }
+    params: dict = {"embed": jax.random.normal(keys[0], (V, D)) * 0.02}
+    specs: dict = {"embed": ("vocab", "embed")}
+    if not cfg.tie_embeddings:
+        params["lm_head"] = jax.random.normal(keys[1], (D, V)) / math.sqrt(D)
+        specs["lm_head"] = ("embed", "vocab")
     params["final_norm"], specs["final_norm"] = L.init_norm(cfg)
 
     blocks_p, blocks_s = {}, {}
@@ -139,6 +136,30 @@ def _block_fwd(cfg: ArchConfig, block_params, x, positions):
     return x, aux_total
 
 
+def _embed(cfg: ArchConfig, params, tokens, *, sharded: bool = True):
+    """Token embeddings: the residual stream's first value, in float32
+    where ``residual_in_fp32`` holds, else in the compute dtype.
+    ``sharded`` constrains them to the activation layout (decode's
+    one-token rows are left as the gather makes them)."""
+    cd = jnp.dtype(cfg.compute_dtype)
+    with jax.named_scope("embed"):
+        h = params["embed"].astype(cd)[tokens]
+        if cfg.residual_in_fp32:
+            h = h.astype(jnp.float32)
+        return constrain(h, "batch", None, "embed_act") if sharded else h
+
+
+def _head(cfg: ArchConfig, params, h):
+    """Final norm and LM head -> float32 logits; a tied head is the
+    embedding, transposed."""
+    cd = jnp.dtype(cfg.compute_dtype)
+    with jax.named_scope("lm_head"):
+        h = L.apply_norm(cfg, params["final_norm"], h)
+        w = (params["embed"].T if cfg.tie_embeddings
+             else params["lm_head"])
+        return jnp.matmul(h, w.astype(cd), preferred_element_type=jnp.float32)
+
+
 def _positions_for(cfg: ArchConfig, tokens, offset: int = 0):
     B, Sq = tokens.shape[0], tokens.shape[1]
     pos = jnp.arange(Sq, dtype=jnp.int32)[None, :] + offset
@@ -153,10 +174,7 @@ def _positions_for(cfg: ArchConfig, tokens, offset: int = 0):
 def forward(cfg: ArchConfig, params, tokens, positions=None
             ) -> tuple[jax.Array, jax.Array]:
     """tokens: (B, S) int32 -> logits (B, S, V) in f32, aux loss."""
-    cd = jnp.dtype(cfg.compute_dtype)
-    with jax.named_scope("embed"):
-        h = params["embed"].astype(cd)[tokens]
-        h = constrain(h, "batch", None, "embed_act")
+    h = _embed(cfg, params, tokens)
     if positions is None:
         positions = _positions_for(cfg, tokens)
 
@@ -178,11 +196,7 @@ def forward(cfg: ArchConfig, params, tokens, positions=None
     (h, aux), _ = jax.lax.scan(scan_fn, (h, jnp.float32(0.0)),
                                params["blocks"],
                                unroll=cfg.n_blocks if cfg.scan_unroll else 1)
-    with jax.named_scope("lm_head"):
-        h = L.apply_norm(cfg, params["final_norm"], h)
-        logits = jnp.matmul(h, params["lm_head"].astype(cd),
-                            preferred_element_type=jnp.float32)
-        logits = constrain(logits, "batch", None, "vocab")
+    logits = constrain(_head(cfg, params, h), "batch", None, "vocab")
     return logits, aux
 
 
@@ -242,9 +256,7 @@ def prefill(cfg: ArchConfig, params, tokens, max_len: int | None = None
     B, Sp = tokens.shape
     max_len = max_len or Sp
     cd = jnp.dtype(cfg.compute_dtype)
-    with jax.named_scope("embed"):
-        h = params["embed"].astype(cd)[tokens]
-        h = constrain(h, "batch", None, "embed_act")
+    h = _embed(cfg, params, tokens)
     positions = _positions_for(cfg, tokens)
     cache = init_cache(cfg, B, max_len)
 
@@ -285,20 +297,14 @@ def prefill(cfg: ArchConfig, params, tokens, max_len: int | None = None
 
     h, new_cache = jax.lax.scan(scan_fn, h, (params["blocks"], cache),
                                 unroll=cfg.n_blocks if cfg.scan_unroll else 1)
-    with jax.named_scope("lm_head"):
-        h = L.apply_norm(cfg, params["final_norm"], h[:, -1:])
-        logits = jnp.matmul(h, params["lm_head"].astype(cd),
-                            preferred_element_type=jnp.float32)
-    return logits[:, 0], new_cache
+    return _head(cfg, params, h[:, -1:])[:, 0], new_cache
 
 
 def decode_step(cfg: ArchConfig, params, cache, tokens, pos
                 ) -> tuple[jax.Array, Tree]:
     """One decode step. tokens: (B, 1) int32; pos: scalar int32 (number
     of tokens already in the cache). Returns (logits (B, V), cache)."""
-    cd = jnp.dtype(cfg.compute_dtype)
-    with jax.named_scope("embed"):
-        h = params["embed"].astype(cd)[tokens]
+    h = _embed(cfg, params, tokens, sharded=False)
 
     def scan_fn(carry, xs):
         x = carry
@@ -329,8 +335,4 @@ def decode_step(cfg: ArchConfig, params, cache, tokens, pos
 
     h, new_cache = jax.lax.scan(scan_fn, h, (params["blocks"], cache),
                                 unroll=cfg.n_blocks if cfg.scan_unroll else 1)
-    with jax.named_scope("lm_head"):
-        h = L.apply_norm(cfg, params["final_norm"], h)
-        logits = jnp.matmul(h, params["lm_head"].astype(cd),
-                            preferred_element_type=jnp.float32)
-    return logits[:, 0], new_cache
+    return _head(cfg, params, h)[:, 0], new_cache

@@ -1,15 +1,17 @@
 """Mamba-2 (SSD) sequence-mixer block (arXiv:2405.21060), used by
 mamba2-2.7b and the jamba hybrid's SSM layers.
 
-Structure per block:
+Structure per block, as ``Mamba2`` in mamba_ssm/modules/mamba2.py:
   in_proj -> [z | x | B | C | dt]
-  causal conv1d (width 4) over [x | B | C], SiLU
+  causal depthwise conv1d (width 4, with bias) over [x | B | C], SiLU
   dt = softplus(dt_raw + dt_bias);  a = -exp(A_log) * dt
-  y = SSD(x * dt, a, B, C) + D * (x * dt)        (kernels.ops.ssd)
+  y = SSD(x * dt, a, B, C) + D * x
   y = RMSNorm(y * silu(z));  out = y @ out_proj
 
-Decode keeps (conv window, SSD state) caches — both O(1) in sequence
-length, which is why the long_500k cell runs on this family.
+The projections, conv and gated norm trace under the ``ssm`` scope and
+the recurrence with its skip term under ``ssm_scan``, side by side, so
+a profile splits the two. Decode keeps (conv window, SSD state) caches,
+both O(1) in sequence length.
 """
 
 from __future__ import annotations
@@ -61,105 +63,77 @@ def init_ssm(cfg, key):
     return p, s
 
 
-def _causal_conv(xbc, conv_w, conv_b, prev=None):
-    """Depthwise causal conv1d. xbc: (B, S, Cdim); conv_w: (K, Cdim).
-    prev: (B, K-1, Cdim) decode window or None (zero history)."""
-    K = conv_w.shape[0]
-    if prev is None:
-        pad = jnp.zeros((xbc.shape[0], K - 1, xbc.shape[2]), xbc.dtype)
-    else:
-        pad = prev.astype(xbc.dtype)
-    xp = jnp.concatenate([pad, xbc], axis=1)
-    out = sum(xp[:, i:i + xbc.shape[1], :] * conv_w[i][None, None]
-              for i in range(K))
-    return out + conv_b[None, None]
+@scoped("ssm")
+def _mix_in(cfg, p, x, window=None):
+    """in_proj, the causal conv and dt for x: (B, S, D). ``window``:
+    (B, K-1, conv_dim) of the conv's inputs before x, or None for zero
+    history. Returns (z, x heads (B, S, nh, ph), B and C (B, S, G, N),
+    dt (B, S, nh) f32, a (B, S, nh) f32, the conv's next window)."""
+    Bt, S, _ = x.shape
+    din, gn, nh = _splits(cfg)
+    K = cfg.ssm_conv_width
+    proj = x @ p["in_proj"].astype(x.dtype)
+    z, xbc, dt_raw = jnp.split(proj, [din, 2 * din + 2 * gn], axis=-1)
+    if window is None:
+        window = jnp.zeros((Bt, K - 1, xbc.shape[-1]), x.dtype)
+    xp = jnp.concatenate([window.astype(x.dtype), xbc], axis=1)
+    w = p["conv_w"].astype(x.dtype)
+    conv = sum(xp[:, i:i + S] * w[i] for i in range(K))
+    xbc = jax.nn.silu(conv + p["conv_b"].astype(x.dtype))
+    xin, bb, cc = jnp.split(xbc, [din, din + gn], axis=-1)
+    xin = constrain(xin, "batch", None, "ssm_inner")
+    dt = jax.nn.softplus(dt_raw.astype(jnp.float32) + p["dt_bias"])
+    a = -jnp.exp(p["A_log"].astype(jnp.float32)) * dt
+    G, N = cfg.ssm_groups, cfg.ssm_state
+    return (z, xin.reshape(Bt, S, nh, cfg.ssm_head_dim),
+            bb.reshape(Bt, S, G, N), cc.reshape(Bt, S, G, N), dt, a,
+            xp[:, S:])
 
 
 @scoped("ssm")
-def ssm_fwd(cfg, p, x):
-    """Training path. x: (B, S, D) -> (B, S, D)."""
-    B, S, D = x.shape
-    din, gn, nh = _splits(cfg)
-    ph = cfg.ssm_head_dim
-    proj = x @ p["in_proj"].astype(x.dtype)
-    z, xin, bb, cc, dt_raw = jnp.split(
-        proj, [din, 2 * din, 2 * din + gn, 2 * din + 2 * gn], axis=-1)
-    xbc = jnp.concatenate([xin, bb, cc], axis=-1)
-    xbc = jax.nn.silu(_causal_conv(xbc, p["conv_w"].astype(x.dtype),
-                                   p["conv_b"].astype(x.dtype)))
-    xin, bb, cc = jnp.split(xbc, [din, din + gn], axis=-1)
-    xin = constrain(xin, "batch", None, "ssm_inner")
-
-    dt = jax.nn.softplus(dt_raw.astype(jnp.float32)
-                         + p["dt_bias"][None, None])        # (B,S,nh)
-    a = -jnp.exp(p["A_log"])[None, None] * dt               # (B,S,nh)
-    xh = xin.reshape(B, S, nh, ph)
-    xh = xh * dt[..., None].astype(xh.dtype)
-    bg = bb.reshape(B, S, cfg.ssm_groups, cfg.ssm_state)
-    cg = cc.reshape(B, S, cfg.ssm_groups, cfg.ssm_state)
-
-    y, _ = ops.ssd(xh, a, bg, cg, chunk=min(128, max(16, S)))
-    y = y + p["D"][None, None, :, None].astype(y.dtype) * xh
-    y = y.reshape(B, S, din)
-    y = ref.rmsnorm_rows(y * jax.nn.silu(z), p["norm"])
-    out = y @ p["out_proj"].astype(x.dtype)
+def _mix_out(cfg, p, y, z):
+    """Gated RMSNorm of y (B, S, nh, ph) and out_proj -> (B, S, D)."""
+    y = y.reshape(z.shape)
+    y = ref.rmsnorm_rows(y * jax.nn.silu(z), p["norm"], cfg.rms_norm_eps)
+    out = y @ p["out_proj"].astype(y.dtype)
     return constrain(out, "batch", None, "embed_act")
 
 
-@scoped("ssm")
+def _skip(p, y, xh):
+    """The skip term D * x, on x before its dt scaling."""
+    return y + p["D"].astype(y.dtype)[:, None] * xh
+
+
+def ssm_fwd(cfg, p, x):
+    """Training path. x: (B, S, D) -> (B, S, D)."""
+    S = x.shape[1]
+    z, xh, bg, cg, dt, a, _ = _mix_in(cfg, p, x)
+    with jax.named_scope("ssm_scan"):
+        y, _ = ops.ssd(xh * dt[..., None].astype(xh.dtype), a, bg, cg,
+                       chunk=min(128, max(16, S)))
+        y = _skip(p, y, xh)
+    return _mix_out(cfg, p, y, z)
+
+
 def ssm_fwd_with_cache(cfg, p, x):
-    """Prefill returning decode caches (conv window + SSD state)."""
-    B, S, D = x.shape
-    din, gn, nh = _splits(cfg)
-    ph = cfg.ssm_head_dim
-    Kw = cfg.ssm_conv_width
-    proj = x @ p["in_proj"].astype(x.dtype)
-    z, xin, bb, cc, dt_raw = jnp.split(
-        proj, [din, 2 * din, 2 * din + gn, 2 * din + 2 * gn], axis=-1)
-    xbc_pre = jnp.concatenate([xin, bb, cc], axis=-1)
-    xbc = jax.nn.silu(_causal_conv(xbc_pre, p["conv_w"].astype(x.dtype),
-                                   p["conv_b"].astype(x.dtype)))
-    xin2, bb2, cc2 = jnp.split(xbc, [din, din + gn], axis=-1)
-    dt = jax.nn.softplus(dt_raw.astype(jnp.float32)
-                         + p["dt_bias"][None, None])
-    a = -jnp.exp(p["A_log"])[None, None] * dt
-    xh = xin2.reshape(B, S, nh, ph) * dt[..., None].astype(x.dtype)
-    bg = bb2.reshape(B, S, cfg.ssm_groups, cfg.ssm_state)
-    cg = cc2.reshape(B, S, cfg.ssm_groups, cfg.ssm_state)
-    y, state = ref.ssd_scan(xh, a, bg, cg)
-    y = y + p["D"][None, None, :, None].astype(y.dtype) * xh
-    y = y.reshape(B, S, din)
-    y = ref.rmsnorm_rows(y * jax.nn.silu(z), p["norm"])
-    out = y @ p["out_proj"].astype(x.dtype)
-    conv_window = xbc_pre[:, -(Kw - 1):, :]     # (B, K-1, conv_dim)
-    return out, state.astype(jnp.float32), conv_window
+    """Prefill returning (out, SSD state (B, nh, ph, N) f32, conv window
+    (B, K-1, conv_dim))."""
+    z, xh, bg, cg, dt, a, window = _mix_in(cfg, p, x)
+    with jax.named_scope("ssm_scan"):
+        y, state = ref.ssd_scan(xh * dt[..., None].astype(xh.dtype), a, bg,
+                                cg)
+        y = _skip(p, y, xh)
+    return _mix_out(cfg, p, y, z), state, window
 
 
-@scoped("ssm")
 def ssm_decode(cfg, p, x, conv_window, state):
     """Single-token decode. x: (B, 1, D); conv_window: (B, K-1, conv_dim);
     state: (B, nh, ph, N). Returns (out, conv_window, state)."""
-    B = x.shape[0]
-    din, gn, nh = _splits(cfg)
-    ph = cfg.ssm_head_dim
-    proj = x @ p["in_proj"].astype(x.dtype)
-    z, xin, bb, cc, dt_raw = jnp.split(
-        proj, [din, 2 * din, 2 * din + gn, 2 * din + 2 * gn], axis=-1)
-    xbc_t = jnp.concatenate([xin, bb, cc], axis=-1)       # (B, 1, conv_dim)
-    window = jnp.concatenate([conv_window, xbc_t], axis=1)  # (B, K, cd)
-    conv_out = (window * p["conv_w"][None].astype(x.dtype)).sum(axis=1) \
-        + p["conv_b"][None].astype(x.dtype)               # (B, cd)
-    conv_out = jax.nn.silu(conv_out)
-    xin2, bb2, cc2 = jnp.split(conv_out, [din, din + gn], axis=-1)
-    dt = jax.nn.softplus(dt_raw[:, 0].astype(jnp.float32)
-                         + p["dt_bias"][None])             # (B, nh)
-    a = -jnp.exp(p["A_log"])[None] * dt
-    xh = xin2.reshape(B, nh, ph) * dt[..., None].astype(x.dtype)
-    bg = bb2.reshape(B, cfg.ssm_groups, cfg.ssm_state)
-    cg = cc2.reshape(B, cfg.ssm_groups, cfg.ssm_state)
-    y, state = ops.ssd_decode_step(xh, a, bg, cg, state)
-    y = y + p["D"][None, :, None].astype(y.dtype) * xh
-    y = y.reshape(B, 1, din)
-    y = ref.rmsnorm_rows(y * jax.nn.silu(z), p["norm"])
-    out = y @ p["out_proj"].astype(x.dtype)
-    return out, window[:, 1:, :], state
+    z, xh, bg, cg, dt, a, window = _mix_in(cfg, p, x, conv_window)
+    with jax.named_scope("ssm_scan"):
+        xt = xh[:, 0]
+        y, state = ops.ssd_decode_step(
+            xt * dt[:, 0, :, None].astype(xt.dtype), a[:, 0], bg[:, 0],
+            cg[:, 0], state)
+        y = _skip(p, y, xt)[:, None]
+    return _mix_out(cfg, p, y, z), window, state

@@ -1,0 +1,129 @@
+"""mamba2 against a plain float32 Mamba-2 written out here (reduced
+sizes, CPU): the training forward, and prefill followed by decode steps
+through the conv window and SSD state. Also the parameter counts of the
+SSM-bearing configs and the tied head."""
+
+import dataclasses
+
+import jax
+import jax.numpy as jnp
+import numpy as np
+import pytest
+
+from repro.configs import ARCH_IDS, get_config
+from repro.models import lm
+
+HI = jax.lax.Precision.HIGHEST
+# float32 on both sides: the program's scan orders its sums unlike the
+# token loop below, which moved logits of size ~0.6 by 2.4e-7 on the CPU;
+# the old skip term moved them by 0.67
+TOL = 1e-5
+
+
+def _rms(x, w, eps):
+    return x * jax.lax.rsqrt(jnp.mean(x * x, -1, keepdims=True) + eps) * w
+
+
+def plain_mamba2(cfg, params, tokens, skip="x"):
+    """Logits (S, V) of one sequence, token by token: per layer
+    h += out_proj(rms(y * silu(z))), y_t = C_t s_t + D x_t with
+    s_t = exp(dt_t A) s_{t-1} + dt_t x_t B_tᵀ. ``skip="x_dt"`` adds
+    D (x_t dt_t) instead, the form the program once had."""
+    H, P, N = cfg.ssm_heads, cfg.ssm_head_dim, cfg.ssm_state
+    din, K, eps = cfg.ssm_inner, cfg.ssm_conv_width, cfg.rms_norm_eps
+    h = params["embed"][tokens]
+    for i in range(cfg.n_layers):
+        lp = jax.tree.map(lambda a: a[i], params["blocks"]["pos0"])
+        p = lp["ssm"]
+        proj = jnp.dot(_rms(h, lp["norm1"]["scale"], eps), p["in_proj"],
+                       precision=HI)
+        z, xbc, dt = proj[:, :din], proj[:, din:-H], proj[:, -H:]
+        xbc = jnp.concatenate([jnp.zeros((K - 1, xbc.shape[1])), xbc])
+        xbc = jax.nn.silu(sum(xbc[k:k + len(tokens)] * p["conv_w"][k]
+                              for k in range(K)) + p["conv_b"])
+        x, b, c = xbc[:, :din], xbc[:, din:din + N], xbc[:, din + N:]
+        dt = jax.nn.softplus(dt + p["dt_bias"])
+        A = -jnp.exp(p["A_log"])
+        s = jnp.zeros((H, P, N))
+        ys = []
+        for t in range(len(tokens)):
+            xt = x[t].reshape(H, P)
+            s = (jnp.exp(dt[t] * A)[:, None, None] * s
+                 + (dt[t][:, None] * xt)[..., None] * b[t])
+            y = jnp.einsum("hpn,n->hp", s, c[t], precision=HI)
+            y = y + p["D"][:, None] * xt * (
+                dt[t][:, None] if skip == "x_dt" else 1.0)
+            ys.append(y.reshape(din))
+        y = jnp.stack(ys) * jax.nn.silu(z)
+        h = h + jnp.dot(_rms(y, p["norm"], eps), p["out_proj"], precision=HI)
+    h = _rms(h, params["final_norm"]["scale"], eps)
+    return jnp.dot(h, params["embed"].T, precision=HI)
+
+
+@pytest.fixture(scope="module")
+def model():
+    cfg = get_config("mamba2-2.7b", reduced=True)
+    assert cfg.compute_dtype == "float32" and cfg.tie_embeddings
+    params, _ = lm.init(cfg, jax.random.PRNGKey(3))
+    tokens = jnp.asarray(np.random.default_rng(3).integers(
+        0, cfg.vocab_size, (2, 12)), jnp.int32)
+    return cfg, params, tokens
+
+
+def _prefill_then_decode(cfg, params, tokens, n_prompt):
+    """Logits at positions n_prompt - 1 .. S - 1, served as the server
+    does: prefill of the prompt, then one decode step a token."""
+    S = tokens.shape[1]
+    logits, cache = lm.prefill(cfg, params, tokens[:, :n_prompt], max_len=S)
+    out = [logits]
+    for t in range(n_prompt, S):
+        logits, cache = lm.decode_step(cfg, params, cache,
+                                       tokens[:, t:t + 1], jnp.int32(t))
+        out.append(logits)
+    return jnp.stack(out, 1)
+
+
+@pytest.mark.parametrize("skip", ["x", "x_dt"])
+@pytest.mark.parametrize("path", ["forward", "prefill_decode"])
+def test_program_matches_the_plain_recurrence(model, path, skip):
+    """D·x agrees to float32 rounding on both serving paths; the old
+    D·(x·dt) is off by far more than the tolerance."""
+    cfg, params, tokens = model
+    if path == "forward":
+        got, n0 = lm.forward(cfg, params, tokens)[0], 0
+    else:
+        # a 2-token prompt leaves less than the conv's 3-token window
+        n0 = 2
+        got = _prefill_then_decode(cfg, params, tokens, n0)
+    want = jnp.stack([plain_mamba2(cfg, params, t, skip) for t in tokens])
+    err = float(jnp.max(jnp.abs(got - want[:, max(n0 - 1, 0):])))
+    if skip == "x":
+        assert err < TOL, err
+    else:
+        assert err > 100 * TOL, err
+
+
+def test_tied_head_has_no_leaf_of_its_own(model):
+    cfg, params, _ = model
+    assert "lm_head" not in params
+    untied, _ = lm.init(dataclasses.replace(cfg, tie_embeddings=False),
+                        jax.random.PRNGKey(3))
+    assert untied["lm_head"].shape == (cfg.d_model, cfg.vocab_size)
+
+
+SSM_ARCHS = [a for a in ARCH_IDS
+             if any(p.mixer == "ssm" for p in get_config(a).pattern)]
+
+
+@pytest.mark.parametrize("reduced", [False, True])
+@pytest.mark.parametrize("arch", SSM_ARCHS)
+def test_param_count_equals_the_leaves(arch, reduced):
+    cfg = get_config(arch, reduced=reduced)
+    shapes, _ = lm.abstract_init(cfg)
+    assert sum(x.size for x in jax.tree.leaves(shapes)) == cfg.param_count()
+
+
+def test_published_parameter_counts():
+    assert SSM_ARCHS == ["jamba-1.5-large-398b", "mamba2-2.7b"]
+    assert get_config("mamba2-2.7b").param_count() == 2_702_599_680
+    assert get_config("qwen3-4b").param_count() == 4_411_424_256

@@ -1,6 +1,6 @@
 """Compiles for a described TPU v5e (no chip attached): the Pallas
-kernels at real widths and the qwen3-4b serving steps at its published
-widths. Nothing runs; the chip's compiler refuses what would not fit
+kernels at real widths and the qwen3-4b and mamba2-2.7b serving steps
+at their published widths. Nothing runs; the chip's compiler refuses what would not fit
 VMEM or HBM, and block shapes it cannot tile.
 
 The topology is described inside a module fixture, never at import:
@@ -112,21 +112,20 @@ def test_ssd_compiles_at_mamba2_widths(no_compile_cache, one_chip):
     assert "tpu_custom_call" in compiled.as_text()
 
 
-# ------------------------------------------------- qwen3-4b serving steps
+# ------------------------------------------------------- serving steps
 
 B, MAX_LEN, PROMPT = 8, 2048, 512
 
 
-@pytest.fixture(scope="module")
-def qwen3_serving(topo):
+def _serving(topo, arch):
     """The server's config, rules, steps and sharded parameter shapes
-    for qwen3-4b on one described chip."""
+    for ``arch`` on one described chip."""
     from repro.launch.mesh import make_local_mesh
     from repro.launch.serve import serving_steps
     from repro.models import lm
     from repro.parallel.sharding import make_rules, params_shardings
 
-    cfg = get_config("qwen3-4b")
+    cfg = get_config(arch)
     cfg = dataclasses.replace(cfg, param_dtype=cfg.compute_dtype)
     mesh = make_local_mesh(devices=topo.devices[:1])
     rules = make_rules(cfg, mesh)
@@ -137,6 +136,21 @@ def qwen3_serving(topo):
         shapes, shards)
     prefill, decode = serving_steps(cfg, rules, MAX_LEN)
     return cfg, NamedSharding(mesh, P()), params, prefill, decode
+
+
+def _decode_compiled(serving, batch):
+    from repro.models import lm
+
+    cfg, rep, params, _, decode = serving
+    cache_shapes = jax.eval_shape(lambda: lm.init_cache(cfg, batch, MAX_LEN))
+    cache = jax.tree.map(lambda s: _sds(rep, s.shape, s.dtype), cache_shapes)
+    return decode.lower(params, cache, _sds(rep, (batch, 1), jnp.int32),
+                        _sds(rep, (), jnp.int32)).compile()
+
+
+@pytest.fixture(scope="module")
+def qwen3_serving(topo):
+    return _serving(topo, "qwen3-4b")
 
 
 def test_qwen3_params_are_bf16_at_published_size(qwen3_serving):
@@ -157,13 +171,7 @@ def test_qwen3_prefill_compiles_and_fits(no_compile_cache, qwen3_serving):
 @pytest.fixture(scope="module")
 def qwen3_decode(no_compile_cache, qwen3_serving):
     """The qwen3-4b decode step compiled for one described chip."""
-    from repro.models import lm
-
-    cfg, rep, params, _, decode = qwen3_serving
-    cache_shapes = jax.eval_shape(lambda: lm.init_cache(cfg, B, MAX_LEN))
-    cache = jax.tree.map(lambda s: _sds(rep, s.shape, s.dtype), cache_shapes)
-    return decode.lower(params, cache, _sds(rep, (B, 1), jnp.int32),
-                        _sds(rep, (), jnp.int32)).compile()
+    return _decode_compiled(qwen3_serving, B)
 
 
 def test_qwen3_decode_compiles_and_fits(qwen3_decode):
@@ -191,3 +199,38 @@ def test_qwen3_decode_fusions_keep_layer_scopes(qwen3_decode):
         if m:
             scopes |= set(m.group(1).split("/"))
     assert {"attn", "mlp"} <= scopes
+
+
+# ---------------------------------------------- mamba2-2.7b serving steps
+
+# the chat cell's clients and its widest padded prompt; each step fits
+# 90% of the chip (prefill 12,924,396,032 B and decode 10,843,531,776 B
+# when compiled for a described v5e)
+CHAT_CLIENTS, CHAT_WIDTH = 16, 1536
+FIT = 0.9 * HBM_BYTES
+
+
+@pytest.fixture(scope="module")
+def mamba2_serving(topo):
+    return _serving(topo, "mamba2-2.7b")
+
+
+def test_mamba2_params_are_bf16_at_published_size(mamba2_serving):
+    cfg, _, params, _, _ = mamba2_serving
+    leaves = jax.tree.leaves(params)
+    assert {x.dtype for x in leaves} == {jnp.dtype(jnp.bfloat16)}
+    assert sum(x.size for x in leaves) == cfg.param_count() == 2_702_599_680
+
+
+def test_mamba2_prefill_compiles_and_fits(no_compile_cache, mamba2_serving):
+    _, rep, params, prefill, _ = mamba2_serving
+    tokens = _sds(rep, (CHAT_CLIENTS, CHAT_WIDTH), jnp.int32)
+    compiled = prefill.lower(params, tokens).compile()
+    assert _device_bytes(compiled) < FIT
+
+
+def test_mamba2_decode_compiles_and_fits(no_compile_cache, mamba2_serving):
+    compiled = _decode_compiled(mamba2_serving, CHAT_CLIENTS)
+    assert _device_bytes(compiled) < FIT
+    # the recurrence and its skip term run under their own scope
+    assert 'ssm_scan' in compiled.as_text()

@@ -50,7 +50,7 @@ def test_compiled_serving_steps_carry_layer_scopes(server):
 
 @pytest.mark.parametrize("arch,want", [
     ("qwen3-4b", {"embed", "norm", "attn", "mlp", "lm_head"}),
-    ("mamba2-2.7b", {"ssm"}),
+    ("mamba2-2.7b", {"ssm", "ssm_scan"}),
     ("dbrx-132b", {"moe"})])
 def test_training_forward_carries_layer_scopes(arch, want):
     from repro.configs import get_config
@@ -61,6 +61,28 @@ def test_training_forward_carries_layer_scopes(arch, want):
     text = jax.jit(lambda p, t: lm.forward(cfg, p, t)[0]).lower(
         params, jax.ShapeDtypeStruct((1, 8), jnp.int32)).compile().as_text()
     assert want <= _scopes_in(text) and want <= set(SCOPES)
+
+
+def test_ssm_scan_is_a_sibling_of_ssm():
+    """Both mamba2 serving steps carry the recurrence under ``ssm_scan``
+    and the rest of the layer under ``ssm``, never one inside the other
+    (a profile gives an operation to the outermost scope it knows)."""
+    from repro.configs import get_config
+    from repro.launch.mesh import make_local_mesh
+    from repro.launch.serve import BatchServer
+    server = BatchServer(get_config("mamba2-2.7b", reduced=True),
+                         make_local_mesh(), max_len=16)
+    tokens = jnp.zeros((2, 8), jnp.int32)
+    _, cache = server.prefill_fn(server.params, tokens)
+    for compiled in (
+            server.prefill_fn.lower(server.params, tokens).compile(),
+            server.decode_fn.lower(server.params, cache,
+                                   jnp.zeros((2, 1), jnp.int32),
+                                   jnp.int32(8)).compile()):
+        paths = [set(n.split("/")) for n in OP_NAME.findall(compiled.as_text())]
+        assert any("ssm" in p for p in paths)
+        assert any("ssm_scan" in p for p in paths)
+        assert not any({"ssm", "ssm_scan"} <= p for p in paths)
 
 
 def test_serve_counts_its_work(server):
