@@ -120,15 +120,13 @@ def attention(q, k, v, *, causal: bool = True, kv_len=None):
 
 def ssd(x, a, b, c, *, chunk: int = 128, initial_state=None):
     """Mamba-2 SSD over (B, S, H, P) inputs (see ref.ssd_scan for the
-    contract). Pallas chunked kernel on TPU; jnp chunked oracle (scan)
-    elsewhere — both differentiable paths route to the oracle."""
+    contract). Pallas chunked kernel on TPU; ``ref.ssd_chunked``
+    elsewhere and whenever an initial state is given."""
     B, S, H, P = x.shape
     G = b.shape[2]
     if not _use_pallas(B, S, H, P) or initial_state is not None:
-        if S % chunk == 0 and S > chunk:
-            return ref.ssd_chunked(x, a, b, c, chunk=chunk,
-                                   initial_state=initial_state)
-        return ref.ssd_scan(x, a, b, c, initial_state=initial_state)
+        return ref.ssd_chunked(x, a, b, c, chunk=chunk,
+                               initial_state=initial_state)
     rep = H // G
     pad = (-S) % chunk
     xs = jnp.pad(x, ((0, 0), (0, pad), (0, 0), (0, 0)))

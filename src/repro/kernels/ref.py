@@ -176,52 +176,66 @@ def ssd_scan(x, a, b, c, *, initial_state=None):
 
 
 def ssd_chunked(x, a, b, c, *, chunk: int = 64, initial_state=None):
-    """Chunked SSD (the algorithm the Pallas kernel implements):
-    intra-chunk quadratic attention-like term + inter-chunk state pass.
-    Matches ``ssd_scan`` to fp32 tolerance."""
+    """Mamba-2 SSD in the chunked state-space-duality form (Dao & Gu,
+    arXiv:2405.21060 §6), the algorithm the Pallas kernel implements;
+    the same contract as ``ssd_scan`` and equal to it to float32
+    rounding. Positions split into chunks of ``chunk``:
+
+      inside a chunk : y_t = Σ_{s≤t} exp(acs_t − acs_s) (c_t·b_s) x_s,
+                       acs the cumulative sum of a in the chunk
+      chunk state    : Σ_s exp(acs_last − acs_s) x_s b_sᵀ
+      across chunks  : a scan over the chunks only, carrying the
+                       (B, H, P, N) state into each chunk
+      entering state : y_t += exp(acs_t) c_t · state_inᵀ
+
+    ``c·b`` is formed once per state group and broadcast over the group's
+    heads; b and c are never repeated to the heads. The segment sums are
+    masked to -inf above the diagonal before the exp, so nothing there
+    overflows. Any S: the tail pads to a multiple of ``chunk`` with
+    x = b = c = 0 and a = 0 (decay 1), which leaves the final state and
+    the real positions as they were, and ``y`` is sliced back to S.
+    Operands are float32 and every contraction runs at HIGHEST precision.
+    Returns (y in x's dtype, final state (B, H, P, N) float32)."""
     B, S, H, P = x.shape
-    G, Nst = b.shape[2], b.shape[3]
-    rep = H // G
-    assert S % chunk == 0, (S, chunk)
-    nc = S // chunk
-    xf = x.astype(jnp.float32).reshape(B, nc, chunk, H, P)
-    af = a.astype(jnp.float32).reshape(B, nc, chunk, H)
-    bf = jnp.repeat(b.astype(jnp.float32), rep, axis=2).reshape(
-        B, nc, chunk, H, Nst)
-    cf = jnp.repeat(c.astype(jnp.float32), rep, axis=2).reshape(
-        B, nc, chunk, H, Nst)
+    G, N = b.shape[2], b.shape[3]
+    R = H // G
+    pad = -S % chunk
+    nc = (S + pad) // chunk
+    hi = jax.lax.Precision.HIGHEST
 
-    acs = jnp.cumsum(af, axis=2)                       # (B,nc,L,H)
-    # L[t, s] = exp(acs[t] - acs[s]) for s <= t  (segment sum)
-    seg = acs[:, :, :, None, :] - acs[:, :, None, :, :]
-    tri = jnp.tril(jnp.ones((chunk, chunk), bool))
-    L = jnp.where(tri[None, None, :, :, None], jnp.exp(seg), 0.0)
+    def chunks(t, *tail):
+        t = jnp.pad(t.astype(jnp.float32),
+                    [(0, 0), (0, pad)] + [(0, 0)] * (t.ndim - 2))
+        return t.reshape(B, nc, chunk, *tail)
 
-    # intra-chunk: y_diag[t] = sum_s L[t,s] (c_t . b_s) x_s
-    cb = jnp.einsum("bnthi,bnshi->bnhts", cf, bf)      # (B,nc,H,L,L)
-    Lh = jnp.moveaxis(L, -1, 2)                        # (B,nc,H,L,L)
-    y_diag = jnp.einsum("bnhts,bnshp->bnthp", cb * Lh, xf)
+    xf = chunks(x, G, R, P)
+    bf, cf = chunks(b, G, N), chunks(c, G, N)
+    acs = jnp.cumsum(chunks(a, G, R), axis=2)           # (B,nc,Q,G,R)
 
-    # chunk states: states[n] = sum_s exp(acs[last] - acs[s]) b_s x_s
-    decay_out = jnp.exp(acs[:, :, -1:, :] - acs)       # (B,nc,L,H)
-    states = jnp.einsum("bnsh,bnshi,bnshp->bnhpi", decay_out, bf, xf)
+    # inside each chunk, as (B, nc, G, R, t, s)
+    at = jnp.moveaxis(acs, 2, -1)
+    causal = jnp.tril(jnp.ones((chunk, chunk), bool))
+    decay = jnp.exp(jnp.where(causal, at[..., :, None] - at[..., None, :],
+                              -jnp.inf))
+    cb = jnp.einsum("bntgk,bnsgk->bngts", cf, bf, precision=hi)
+    y = jnp.einsum("bngrts,bnsgrp->bntgrp", decay * cb[:, :, :, None], xf,
+                   precision=hi)
 
-    # inter-chunk recurrence over n
-    chunk_decay = jnp.exp(acs[:, :, -1, :])            # (B,nc,H)
-    s0 = (jnp.zeros((B, H, P, Nst), jnp.float32)
-          if initial_state is None else initial_state.astype(jnp.float32))
+    # each chunk's own state at its end, then the state entering each chunk
+    to_end = jnp.exp(acs[:, :, -1:] - acs)
+    own = jnp.einsum("bnsgrp,bnsgk->bngrpk", xf * to_end[..., None], bf,
+                     precision=hi)
+    s0 = (jnp.zeros((B, H, P, N), jnp.float32) if initial_state is None
+          else initial_state.astype(jnp.float32))
 
-    def step(carry, inp):
-        st_n, dec_n = inp
-        new = dec_n[:, :, None, None] * carry + st_n
-        return new, carry    # emit state *entering* the chunk
+    def step(state, inp):
+        own_n, decay_n = inp
+        return decay_n[..., None, None] * state + own_n, state
 
-    final, prevs = jax.lax.scan(
-        step, s0, (jnp.moveaxis(states, 1, 0), jnp.moveaxis(chunk_decay, 1, 0)))
-    prev_states = jnp.moveaxis(prevs, 0, 1)            # (B,nc,H,P,N)
-
-    # y_off[t] = (c_t . state_prev) * exp(acs[t])
-    y_off = jnp.einsum("bnthi,bnhpi,bnth->bnthp",
-                       cf, prev_states, jnp.exp(acs))
-    y = (y_diag + y_off).reshape(B, S, H, P)
-    return y.astype(x.dtype), final
+    final, entering = jax.lax.scan(
+        step, s0.reshape(B, G, R, P, N),
+        (jnp.moveaxis(own, 1, 0), jnp.moveaxis(jnp.exp(acs[:, :, -1]), 1, 0)))
+    y += jnp.einsum("bntgk,nbgrpk->bntgrp", cf, entering,
+                    precision=hi) * jnp.exp(acs)[..., None]
+    y = y.reshape(B, nc * chunk, H, P)[:, :S]
+    return y.astype(x.dtype), final.reshape(B, H, P, N)

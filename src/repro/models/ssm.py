@@ -12,6 +12,17 @@ The projections, conv and gated norm trace under the ``ssm`` scope and
 the recurrence with its skip term under ``ssm_scan``, side by side, so
 a profile splits the two. Decode keeps (conv window, SSD state) caches,
 both O(1) in sequence length.
+
+Prefill runs the recurrence in the chunked state-space-duality form
+(``ref.ssd_chunked``; paper §6): inside a chunk of min(128, max(16, S))
+positions, the masked decay times C Bᵀ (formed once per state group)
+times X, as matmuls; each chunk's end state as (B ∘ decay)ᵀ X; a scan
+over the chunks alone carrying the f32 state; and the entering state's
+term exp(cumsum a) ∘ C·Sᵀ. The prompt pads at its end to a whole chunk
+with x = b = c = 0 and a = 0, which changes neither the real positions
+nor the final state decode starts from. Operands are float32 and every
+contraction runs at HIGHEST precision, as in the token recurrence it
+replaces. Decode advances the state one token a step.
 """
 
 from __future__ import annotations
@@ -104,24 +115,29 @@ def _skip(p, y, xh):
     return y + p["D"].astype(y.dtype)[:, None] * xh
 
 
+def _chunk(S):
+    """Positions a chunk of the SSD spans, from the sequence length."""
+    return min(128, max(16, S))
+
+
 def ssm_fwd(cfg, p, x):
     """Training path. x: (B, S, D) -> (B, S, D)."""
-    S = x.shape[1]
     z, xh, bg, cg, dt, a, _ = _mix_in(cfg, p, x)
     with jax.named_scope("ssm_scan"):
         y, _ = ops.ssd(xh * dt[..., None].astype(xh.dtype), a, bg, cg,
-                       chunk=min(128, max(16, S)))
+                       chunk=_chunk(x.shape[1]))
         y = _skip(p, y, xh)
     return _mix_out(cfg, p, y, z)
 
 
 def ssm_fwd_with_cache(cfg, p, x):
     """Prefill returning (out, SSD state (B, nh, ph, N) f32, conv window
-    (B, K-1, conv_dim))."""
+    (B, K-1, conv_dim)), the recurrence in the chunked SSD form
+    (``ref.ssd_chunked``) with the chunk taken from the prompt's width."""
     z, xh, bg, cg, dt, a, window = _mix_in(cfg, p, x)
     with jax.named_scope("ssm_scan"):
-        y, state = ref.ssd_scan(xh * dt[..., None].astype(xh.dtype), a, bg,
-                                cg)
+        y, state = ref.ssd_chunked(xh * dt[..., None].astype(xh.dtype), a,
+                                   bg, cg, chunk=_chunk(x.shape[1]))
         y = _skip(p, y, xh)
     return _mix_out(cfg, p, y, z), state, window
 

@@ -1,7 +1,8 @@
 """mamba2 against a plain float32 Mamba-2 written out here (reduced
 sizes, CPU): the training forward, and prefill followed by decode steps
-through the conv window and SSD state. Also the parameter counts of the
-SSM-bearing configs and the tied head."""
+through the conv window and SSD state; prefill's chunked SSD against the
+token recurrence. Also the parameter counts of the SSM-bearing configs
+and the tied head."""
 
 import dataclasses
 
@@ -11,7 +12,9 @@ import numpy as np
 import pytest
 
 from repro.configs import ARCH_IDS, get_config
-from repro.models import lm
+from repro.kernels import ref
+from repro.launch.serve import Request, left_pad
+from repro.models import layers, lm, ssm
 
 HI = jax.lax.Precision.HIGHEST
 # float32 on both sides: the program's scan orders its sums unlike the
@@ -101,6 +104,40 @@ def test_program_matches_the_plain_recurrence(model, path, skip):
         assert err < TOL, err
     else:
         assert err > 100 * TOL, err
+
+
+# prompt lengths of a batch of two, padded on the left to the longest;
+# the chunk is min(128, max(16, S)) positions
+PROMPTS = {
+    "under_the_conv_window": (2, 2),
+    "whole_chunks": (256, 256),
+    "tail_pad": (200, 200),
+    "left_padded": (200, 77),
+}
+
+
+@pytest.mark.parametrize("lengths", PROMPTS.values(), ids=PROMPTS.keys())
+def test_prefill_ssd_matches_the_token_recurrence(model, lengths):
+    """``ssm_fwd_with_cache``'s chunked SSD gives the token-by-token
+    recurrence's output, final state and conv window to float32
+    rounding, whatever the prompt's length."""
+    cfg, params, _ = model
+    rng = np.random.default_rng(sum(lengths))
+    tokens = jnp.asarray(left_pad([
+        Request(i, rng.integers(1, cfg.vocab_size, n).astype(np.int32))
+        for i, n in enumerate(lengths)]))
+    lp = jax.tree.map(lambda a: a[0], params["blocks"]["pos0"])
+    p = lp["ssm"]
+    x = layers.apply_norm(cfg, lp["norm1"], params["embed"][tokens])
+    got = ssm.ssm_fwd_with_cache(cfg, p, x)
+
+    z, xh, bg, cg, dt, a, window = ssm._mix_in(cfg, p, x)
+    y, state = ref.ssd_scan(xh * dt[..., None], a, bg, cg)
+    want = ssm._mix_out(cfg, p, ssm._skip(p, y, xh), z), state, window
+    for g, w in zip(got, want):
+        assert g.shape == w.shape
+        scale = float(jnp.max(jnp.abs(w)))
+        assert float(jnp.max(jnp.abs(g - w))) < TOL * scale
 
 
 def test_tied_head_has_no_leaf_of_its_own(model):
