@@ -68,7 +68,7 @@ def mha_attention(q, k, v, *, causal: bool = True, scale: float | None = None,
                   kv_len: jax.Array | None = None):
     """Grouped-query attention oracle, the tests' reference. It upcasts
     K and V to float32 and repeats them to Hq heads. Of the serving
-    path only prefill below ``attn_chunk_threshold`` calls it; decode
+    path only prefill below ``layers.ATTN_CHUNK_THRESHOLD`` calls it; decode
     contracts the cache by query group (``layers.attention_decode``).
 
     q: (B, Hq, Sq, D); k, v: (B, Hkv, Skv, D); Hq % Hkv == 0.

@@ -93,11 +93,8 @@ def make_train_step(cfg: ArchConfig, mesh: Mesh, shape: ShapeSpec,
     opt = opt or adamw.OptConfig(moment_dtype=cfg.moment_dtype)
     st = abstract_state(cfg, mesh, opt)
     rules = st["rules"]
-    model = _model_mod(cfg)
     b_sh = _batch_shardings(cfg, shape, rules)
     specs = input_specs(cfg, shape)
-
-    mb = max(int(getattr(cfg, "microbatch", 1)), 1)
 
     def _loss(p, b):
         if cfg.is_encdec:
@@ -107,29 +104,7 @@ def make_train_step(cfg: ArchConfig, mesh: Mesh, shape: ShapeSpec,
 
     def train_step(params, opt_state, batch):
         with use_rules(rules):
-            if mb == 1:
-                loss, grads = jax.value_and_grad(_loss)(params, batch)
-            else:
-                # gradient accumulation: microbatch scan cuts the
-                # activation/logits working set by mb at the cost of mb
-                # sequential sub-steps
-                mbatch = jax.tree.map(
-                    lambda x: x.reshape(mb, x.shape[0] // mb, *x.shape[1:]),
-                    batch)
-                zero = jax.tree.map(
-                    lambda p: jnp.zeros(p.shape, jnp.float32), params)
-
-                def body(acc, mb_b):
-                    l, g = jax.value_and_grad(_loss)(params, mb_b)
-                    return (acc[0] + l,
-                            jax.tree.map(lambda a, b_: a + b_, acc[1], g)), None
-
-                (loss, grads), _ = jax.lax.scan(
-                    body, (jnp.float32(0.0), zero), mbatch,
-                    unroll=mb if cfg.scan_unroll else 1)
-                loss = loss / mb
-                grads = jax.tree.map(lambda g: (g / mb).astype(g.dtype),
-                                     grads)
+            loss, grads = jax.value_and_grad(_loss)(params, batch)
             params, opt_state, om = adamw.apply_updates(
                 params, grads, opt_state, opt)
         metrics = {"loss": loss, **om}
@@ -205,8 +180,7 @@ def _cache_shardings(cfg: ArchConfig, rules: ShardingRules, batch: int,
 
     seq_axes: list[str] = []
     batch_bad = batch % max(dp, 1) != 0
-    kv_eff = cfg.n_kv_heads * getattr(cfg, "kv_cache_repeat", 1)
-    kv_bad = kv_eff > 0 and kv_eff % max(tp, 1) != 0
+    kv_bad = cfg.n_kv_heads > 0 and cfg.n_kv_heads % max(tp, 1) != 0
     if batch_bad and "data" in rules.mesh.shape:
         seq_axes.append("data")
     if kv_bad and "model" in rules.mesh.shape:

@@ -71,14 +71,6 @@ class ArchConfig:
 
     # numerics / memory
     scan_unroll: bool = False   # unroll layer scans (dry-run cost probes)
-    remat_policy: str = "nothing"   # nothing | dots | dots_nb
-    microbatch: int = 1         # gradient-accumulation microbatches
-    attn_chunk_threshold: int = 8192  # use online-softmax chunked
-                                      # attention at/after this seq len
-    kv_cache_repeat: int = 1    # replicate KV heads in the decode cache
-                                # so kv_heads*repeat divides the model
-                                # axis: trades cache bytes for a local
-                                # (no-reshard) cache update
     param_dtype: str = "float32"
     compute_dtype: str = "bfloat16"
     moment_dtype: str = "float32"   # bf16 for the >=100B configs
@@ -86,11 +78,6 @@ class ArchConfig:
 
     # distribution knobs (consumed by repro.parallel.sharding)
     fsdp: bool = False        # shard "embed"-like param dims over data
-    tp_attention: bool = True
-    seq_parallel: bool = False  # sequence-parallel TP: shard the token
-                                # dim over "model" between blocks so TP
-                                # all-reduces become reduce-scatter +
-                                # all-gather (Korthikanti et al.)
 
     def __post_init__(self):
         assert self.n_layers % len(self.pattern) == 0, \

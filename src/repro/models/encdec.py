@@ -133,27 +133,52 @@ def encode(cfg: ArchConfig, params, frames) -> jax.Array:
 
 # ------------------------------------------------------------------ decoder
 
+def _dec_layer(cfg: ArchConfig, p, x, self_attn, cross_attn, entry=None):
+    """One decoder layer: causal self-attention, cross-attention over the
+    encoder states, MLP, each pre-normed and added to the residual.
+    ``self_attn`` and ``cross_attn`` are ``fn(params, h, cache entry) ->
+    (out, new cache entries)``; training passes no entry. Returns (x,
+    new entry)."""
+    hn = L.apply_norm(cfg, p["norm1"], x)
+    mix, new_self = self_attn(p["self_attn"], hn, entry)
+    x = x + mix
+    hn = L.apply_norm(cfg, p["norm2"], x)
+    mix, new_cross = cross_attn(p["cross_attn"], hn, entry)
+    x = x + mix
+    hn = L.apply_norm(cfg, p["norm3"], x)
+    return x + L.mlp_fwd(cfg, p["mlp"], hn), new_self | new_cross
+
+
+def _embed(cfg: ArchConfig, params, tokens) -> jax.Array:
+    """Token embeddings plus sinusoidal positions 0..S-1."""
+    cd = jnp.dtype(cfg.compute_dtype)
+    return params["embed"].astype(cd)[tokens] \
+        + sinusoidal(tokens.shape[1], cfg.d_model).astype(cd)[None]
+
+
+def _head(cfg: ArchConfig, params, h) -> jax.Array:
+    """Final norm and LM head -> float32 logits."""
+    h = L.apply_norm(cfg, params["final_norm"], h)
+    return jnp.matmul(h, params["lm_head"].astype(cfg.compute_dtype),
+                      preferred_element_type=jnp.float32)
+
+
 def forward(cfg: ArchConfig, params, frames, tokens
             ) -> tuple[jax.Array, jax.Array]:
     """Teacher-forced training pass: (frames, tokens) -> logits."""
     enc = encode(cfg, params, frames)
-    cd = jnp.dtype(cfg.compute_dtype)
-    B, S = tokens.shape
-    h = params["embed"].astype(cd)[tokens] \
-        + sinusoidal(S, cfg.d_model).astype(cd)[None]
-    h = constrain(h, "batch", None, "embed_act")
+    h = constrain(_embed(cfg, params, tokens), "batch", None, "embed_act")
+
+    def self_attn(p, hn, _):
+        return L.attention_fwd(cfg, p, hn, None, causal=True)[0], {}
+
+    def cross_attn(p, hn, _):
+        kv = L.encode_kv(cfg, p, enc)
+        return L.attention_fwd(cfg, p, hn, None, causal=False,
+                               kv_override=kv)[0], {}
 
     def body(p, x):
-        hn = L.apply_norm(cfg, p["norm1"], x)
-        mix, _ = L.attention_fwd(cfg, p["self_attn"], hn, None, causal=True)
-        x = x + mix
-        hn = L.apply_norm(cfg, p["norm2"], x)
-        kv = L.encode_kv(cfg, p["cross_attn"], enc)
-        mix, _ = L.attention_fwd(cfg, p["cross_attn"], hn, None,
-                                 causal=False, kv_override=kv)
-        x = x + mix
-        hn = L.apply_norm(cfg, p["norm3"], x)
-        return x + L.mlp_fwd(cfg, p["mlp"], hn)
+        return _dec_layer(cfg, p, x, self_attn, cross_attn)[0]
 
     if cfg.remat:
         body = jax.checkpoint(body,
@@ -164,9 +189,7 @@ def forward(cfg: ArchConfig, params, frames, tokens
 
     h, _ = jax.lax.scan(scan_fn, h, params["decoder"],
                         unroll=cfg.n_layers if cfg.scan_unroll else 1)
-    h = L.apply_norm(cfg, params["final_norm"], h)
-    logits = jnp.matmul(h, params["lm_head"].astype(cd),
-                        preferred_element_type=jnp.float32)
+    logits = _head(cfg, params, h)
     return constrain(logits, "batch", None, "vocab"), jnp.float32(0.0)
 
 
@@ -193,6 +216,18 @@ def cache_specs(cfg: ArchConfig) -> Tree:
     return {"self_k": ax, "self_v": ax, "cross_k": ax, "cross_v": ax}
 
 
+def _cached_decoder(cfg: ArchConfig, params, h, cache, self_attn,
+                    cross_attn):
+    """The decoder's layer scan in prefill and decode: each layer reads
+    its cache slice, and the scan stacks the new ones."""
+    def scan_fn(x, xs):
+        p, cs = xs
+        return _dec_layer(cfg, p, x, self_attn, cross_attn, cs)
+
+    return jax.lax.scan(scan_fn, h, (params["decoder"], cache),
+                        unroll=cfg.n_layers if cfg.scan_unroll else 1)
+
+
 def prefill(cfg: ArchConfig, params, frames, tokens,
             max_len: int | None = None) -> tuple[jax.Array, Tree]:
     """Encode + teacher-forced prompt pass filling decode caches."""
@@ -201,41 +236,29 @@ def prefill(cfg: ArchConfig, params, frames, tokens,
     B, Sp = tokens.shape
     max_len = max_len or Sp
     cache = init_cache(cfg, B, max_len, enc.shape[1])
-    h = params["embed"].astype(cd)[tokens] \
-        + sinusoidal(Sp, cfg.d_model).astype(cd)[None]
+    h = _embed(cfg, params, tokens)
 
-    def scan_fn(x, xs):
-        p, cs = xs
-        hn = L.apply_norm(cfg, p["norm1"], x)
-        mix, (k, v) = L.attention_fwd(cfg, p["self_attn"], hn, None,
-                                      causal=True)
+    def self_attn(p, hn, cs):
+        mix, (k, v) = L.attention_fwd(cfg, p, hn, None, causal=True)
         sk = jax.lax.dynamic_update_slice(cs["self_k"], k.astype(cd),
                                           (0, 0, 0, 0))
         sv = jax.lax.dynamic_update_slice(cs["self_v"], v.astype(cd),
                                           (0, 0, 0, 0))
-        x = x + mix
-        hn = L.apply_norm(cfg, p["norm2"], x)
-        ck, cv = L.encode_kv(cfg, p["cross_attn"], enc)
-        mix, _ = L.attention_fwd(cfg, p["cross_attn"], hn, None,
-                                 causal=False, kv_override=(ck, cv))
-        x = x + mix
-        hn = L.apply_norm(cfg, p["norm3"], x)
-        x = x + L.mlp_fwd(cfg, p["mlp"], hn)
-        return x, {"self_k": sk, "self_v": sv,
-                   "cross_k": ck.astype(cd), "cross_v": cv.astype(cd)}
+        return mix, {"self_k": sk, "self_v": sv}
 
-    h, cache = jax.lax.scan(scan_fn, h, (params["decoder"], cache),
-                            unroll=cfg.n_layers if cfg.scan_unroll else 1)
-    h = L.apply_norm(cfg, params["final_norm"], h[:, -1:])
-    logits = jnp.matmul(h, params["lm_head"].astype(cd),
-                        preferred_element_type=jnp.float32)
-    return logits[:, 0], cache
+    def cross_attn(p, hn, _):
+        ck, cv = L.encode_kv(cfg, p, enc)
+        mix, _ = L.attention_fwd(cfg, p, hn, None, causal=False,
+                                 kv_override=(ck, cv))
+        return mix, {"cross_k": ck.astype(cd), "cross_v": cv.astype(cd)}
+
+    h, cache = _cached_decoder(cfg, params, h, cache, self_attn, cross_attn)
+    return _head(cfg, params, h[:, -1:])[:, 0], cache
 
 
 def decode_step(cfg: ArchConfig, params, cache, tokens, pos
                 ) -> tuple[jax.Array, Tree]:
     cd = jnp.dtype(cfg.compute_dtype)
-    B = tokens.shape[0]
     h = params["embed"].astype(cd)[tokens]
     # position encoding at `pos` (traced): gather from a (1, D) slice
     d = cfg.d_model
@@ -245,26 +268,16 @@ def decode_step(cfg: ArchConfig, params, cache, tokens, pos
     pe = pe.at[1::2].set(jnp.cos(ang).astype(cd))
     h = h + pe[None, None]
 
-    def scan_fn(x, xs):
-        p, cs = xs
-        hn = L.apply_norm(cfg, p["norm1"], x)
-        mix, sk, sv = L.attention_decode(cfg, p["self_attn"], hn,
-                                         cs["self_k"], cs["self_v"], pos,
+    def self_attn(p, hn, cs):
+        mix, sk, sv = L.attention_decode(cfg, p, hn, cs["self_k"],
+                                         cs["self_v"], pos,
                                          rope=False)   # sinusoidal arch
-        x = x + mix
-        hn = L.apply_norm(cfg, p["norm2"], x)
-        mix, _, _ = L.attention_decode(cfg, p["cross_attn"], hn,
-                                       cs["cross_k"], cs["cross_v"], pos,
-                                       cross=True)
-        x = x + mix
-        hn = L.apply_norm(cfg, p["norm3"], x)
-        x = x + L.mlp_fwd(cfg, p["mlp"], hn)
-        return x, {"self_k": sk, "self_v": sv,
-                   "cross_k": cs["cross_k"], "cross_v": cs["cross_v"]}
+        return mix, {"self_k": sk, "self_v": sv}
 
-    h, cache = jax.lax.scan(scan_fn, h, (params["decoder"], cache),
-                            unroll=cfg.n_layers if cfg.scan_unroll else 1)
-    h = L.apply_norm(cfg, params["final_norm"], h)
-    logits = jnp.matmul(h, params["lm_head"].astype(cd),
-                        preferred_element_type=jnp.float32)
-    return logits[:, 0], cache
+    def cross_attn(p, hn, cs):
+        mix, _, _ = L.attention_decode(cfg, p, hn, cs["cross_k"],
+                                       cs["cross_v"], pos, cross=True)
+        return mix, {"cross_k": cs["cross_k"], "cross_v": cs["cross_v"]}
+
+    h, cache = _cached_decoder(cfg, params, h, cache, self_attn, cross_attn)
+    return _head(cfg, params, h)[:, 0], cache

@@ -31,6 +31,10 @@ Tree = Any
 SCOPES = ("embed", "norm", "attn", "mlp", "moe", "ssm", "ssm_scan",
           "lm_head")
 
+# from this sequence length on, full-sequence attention runs the
+# online-softmax chunked form: the (Sq, Skv) logits never materialize
+ATTN_CHUNK_THRESHOLD = 8192
+
 
 def scoped(name: str):
     """Decorator: trace the function under ``jax.named_scope(name)``."""
@@ -180,9 +184,7 @@ def attention_fwd(cfg, p, x, positions, *, causal: bool = True,
         k_t, v_t = kv_override
     q_t = q.transpose(0, 2, 1, 3)
     q_t = constrain(q_t, "batch_attn", "heads", None, None)
-    if S >= cfg.attn_chunk_threshold:
-        # long sequences: online-softmax chunked attention — the dense
-        # (Sq, Skv) logits tensor must never materialize
+    if S >= ATTN_CHUNK_THRESHOLD:
         out = ref.mha_attention_chunked(q_t, k_t, v_t, causal=causal)
     else:
         out = ref.mha_attention(q_t, k_t, v_t, causal=causal)
@@ -212,10 +214,9 @@ def attention_decode(cfg, p, x, cache_k, cache_v, pos, *,
 
     q is reshaped into the cache's head groups, (B, Hc, Hq // Hc, D),
     and contracted against the cache as stored: no upcast or repeated
-    copy of the cache is made. Hc is read off the cache, so GQA, MHA
-    and a cache holding repeated KV heads take the same path. The
-    products accumulate in float32, and the scale, mask and softmax run
-    on the float32 logits.
+    copy of the cache is made. Hc is read off the cache, so GQA, MQA
+    and MHA take the same path. The products accumulate in float32, and
+    the scale, mask and softmax run on the float32 logits.
 
     For cross-attention the cache holds encoder KV and is not updated.
     Returns (out, cache_k, cache_v).
@@ -226,9 +227,6 @@ def attention_decode(cfg, p, x, cache_k, cache_v, pos, *,
         if cfg.m_rope:
             positions = jnp.broadcast_to(positions[None], (3, B, 1))
         q, k, v = _project_qkv(cfg, p, x, positions, rope=rope)
-        if cfg.kv_cache_repeat > 1:
-            k = jnp.repeat(k, cfg.kv_cache_repeat, axis=2)
-            v = jnp.repeat(v, cfg.kv_cache_repeat, axis=2)
         cache_k = jax.lax.dynamic_update_slice(
             cache_k, k.transpose(0, 2, 1, 3).astype(cache_k.dtype),
             (0, 0, pos, 0))

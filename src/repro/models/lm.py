@@ -105,34 +105,34 @@ def abstract_init(cfg: ArchConfig) -> tuple[Tree, Tree]:
 
 # ------------------------------------------------------------------- blocks
 
-def _layer_fwd(cfg: ArchConfig, p, x, positions, pat):
+def _layer(cfg: ArchConfig, pat, p, x, mixers, entry=None):
+    """One layer: norm1, the pattern position's mixer, the residual,
+    norm2, the FFN ``pat.ffn`` names, the residual. ``mixers`` maps
+    ``pat.mixer`` to ``fn(params, h, cache entry) -> (out, new entry)``;
+    training passes no entry. Returns (x, MoE aux loss, new entry)."""
     h = L.apply_norm(cfg, p["norm1"], x)
-    if pat.mixer == "attn":
-        mix, _ = L.attention_fwd(cfg, p["attn"], h, positions, causal=True)
-    else:
-        mix = S.ssm_fwd(cfg, p["ssm"], h)
+    mix, entry = mixers[pat.mixer](p[pat.mixer], h, entry)
     x = x + mix
     if pat.ffn == "none":
-        return x, 0.0
+        return x, 0.0, entry
     h = L.apply_norm(cfg, p["norm2"], x)
     if pat.ffn == "moe":
         ff, aux = L.moe_fwd(cfg, p["moe"], h)
     else:
         ff, aux = L.mlp_fwd(cfg, p["mlp"], h), 0.0
-    return x + ff, aux
+    return x + ff, aux, entry
 
 
 def _block_fwd(cfg: ArchConfig, block_params, x, positions):
+    mixers = {
+        "attn": lambda p, h, _: (
+            L.attention_fwd(cfg, p, h, positions, causal=True)[0], None),
+        "ssm": lambda p, h, _: (S.ssm_fwd(cfg, p, h), None),
+    }
     aux_total = 0.0
     for pi, pat in enumerate(cfg.pattern):
-        x, aux = _layer_fwd(cfg, block_params[f"pos{pi}"], x, positions, pat)
+        x, aux, _ = _layer(cfg, pat, block_params[f"pos{pi}"], x, mixers)
         aux_total = aux_total + aux
-        if cfg.seq_parallel:
-            # token dim sharded over the model axis between layers:
-            # XLA lowers the surrounding TP all-reduces to
-            # reduce-scatter + all-gather (half the link bytes) and
-            # shards the norms/residuals
-            x = constrain(x, "batch", "seq_sp", "embed_act")
     return x, aux_total
 
 
@@ -180,13 +180,8 @@ def forward(cfg: ArchConfig, params, tokens, positions=None
 
     body = functools.partial(_block_fwd, cfg)
     if cfg.remat:
-        policy = {
-            "dots": jax.checkpoint_policies.dots_saveable,
-            # save weight-matmul outputs only (no-batch-dim dots);
-            # attention scores and elementwise stay rematerialized
-            "dots_nb": jax.checkpoint_policies.checkpoint_dots_with_no_batch_dims,
-        }.get(cfg.remat_policy, jax.checkpoint_policies.nothing_saveable)
-        body = jax.checkpoint(body, policy=policy)
+        body = jax.checkpoint(
+            body, policy=jax.checkpoint_policies.nothing_saveable)
 
     def scan_fn(carry, block_params):
         x, aux = carry
@@ -218,8 +213,7 @@ def init_cache(cfg: ArchConfig, batch: int, max_len: int,
     cache: dict = {}
     for pi, pat in enumerate(cfg.pattern):
         if pat.mixer == "attn":
-            shape = (cfg.n_blocks, batch,
-                     cfg.n_kv_heads * cfg.kv_cache_repeat, max_len,
+            shape = (cfg.n_blocks, batch, cfg.n_kv_heads, max_len,
                      cfg.head_dim)
             cache[f"pos{pi}"] = {"k": jnp.zeros(shape, dtype),
                                  "v": jnp.zeros(shape, dtype)}
@@ -249,6 +243,22 @@ def cache_specs(cfg: ArchConfig) -> Tree:
     return specs
 
 
+def _cached_layers(cfg: ArchConfig, params, h, cache, mixers):
+    """The layer scan of prefill and decode: each block's layers read
+    their cache entries, and the scan stacks the new ones."""
+    def scan_fn(x, xs):
+        block_params, cache_slice = xs
+        new_slice = {}
+        for pi, pat in enumerate(cfg.pattern):
+            x, _, new_slice[f"pos{pi}"] = _layer(
+                cfg, pat, block_params[f"pos{pi}"], x, mixers,
+                cache_slice[f"pos{pi}"])
+        return x, new_slice
+
+    return jax.lax.scan(scan_fn, h, (params["blocks"], cache),
+                        unroll=cfg.n_blocks if cfg.scan_unroll else 1)
+
+
 def prefill(cfg: ArchConfig, params, tokens, max_len: int | None = None
             ) -> tuple[jax.Array, Tree]:
     """Run the prompt, return last-position logits + a filled cache of
@@ -260,43 +270,21 @@ def prefill(cfg: ArchConfig, params, tokens, max_len: int | None = None
     positions = _positions_for(cfg, tokens)
     cache = init_cache(cfg, B, max_len)
 
-    def scan_fn(carry, xs):
-        x = carry
-        block_params, cache_slice = xs
-        new_slice = {}
-        for pi, pat in enumerate(cfg.pattern):
-            p = block_params[f"pos{pi}"]
-            hn = L.apply_norm(cfg, p["norm1"], x)
-            if pat.mixer == "attn":
-                mix, (k, v) = L.attention_fwd(cfg, p["attn"], hn, positions,
-                                              causal=True)
-                with jax.named_scope("attn"):
-                    if cfg.kv_cache_repeat > 1:
-                        k = jnp.repeat(k, cfg.kv_cache_repeat, axis=1)
-                        v = jnp.repeat(v, cfg.kv_cache_repeat, axis=1)
-                    ck = jax.lax.dynamic_update_slice(
-                        cache_slice[f"pos{pi}"]["k"], k.astype(cd),
-                        (0, 0, 0, 0))
-                    cv = jax.lax.dynamic_update_slice(
-                        cache_slice[f"pos{pi}"]["v"], v.astype(cd),
-                        (0, 0, 0, 0))
-                new_slice[f"pos{pi}"] = {"k": ck, "v": cv}
-            else:
-                mix, state, conv = S.ssm_fwd_with_cache(cfg, p["ssm"], hn)
-                new_slice[f"pos{pi}"] = {"conv": conv.astype(cd),
-                                         "state": state}
-            x = x + mix
-            if pat.ffn != "none":
-                hn = L.apply_norm(cfg, p["norm2"], x)
-                if pat.ffn == "moe":
-                    ff, _ = L.moe_fwd(cfg, p["moe"], hn)
-                else:
-                    ff = L.mlp_fwd(cfg, p["mlp"], hn)
-                x = x + ff
-        return x, new_slice
+    def attn(p, hn, c):
+        mix, (k, v) = L.attention_fwd(cfg, p, hn, positions, causal=True)
+        with jax.named_scope("attn"):
+            ck = jax.lax.dynamic_update_slice(c["k"], k.astype(cd),
+                                              (0, 0, 0, 0))
+            cv = jax.lax.dynamic_update_slice(c["v"], v.astype(cd),
+                                              (0, 0, 0, 0))
+        return mix, {"k": ck, "v": cv}
 
-    h, new_cache = jax.lax.scan(scan_fn, h, (params["blocks"], cache),
-                                unroll=cfg.n_blocks if cfg.scan_unroll else 1)
+    def ssm(p, hn, c):
+        mix, state, conv = S.ssm_fwd_with_cache(cfg, p, hn)
+        return mix, {"conv": conv.astype(cd), "state": state}
+
+    h, new_cache = _cached_layers(cfg, params, h, cache,
+                                  {"attn": attn, "ssm": ssm})
     return _head(cfg, params, h[:, -1:])[:, 0], new_cache
 
 
@@ -306,33 +294,14 @@ def decode_step(cfg: ArchConfig, params, cache, tokens, pos
     of tokens already in the cache). Returns (logits (B, V), cache)."""
     h = _embed(cfg, params, tokens, sharded=False)
 
-    def scan_fn(carry, xs):
-        x = carry
-        block_params, cache_slice = xs
-        new_slice = {}
-        for pi, pat in enumerate(cfg.pattern):
-            p = block_params[f"pos{pi}"]
-            hn = L.apply_norm(cfg, p["norm1"], x)
-            if pat.mixer == "attn":
-                c = cache_slice[f"pos{pi}"]
-                mix, ck, cv = L.attention_decode(cfg, p["attn"], hn,
-                                                 c["k"], c["v"], pos)
-                new_slice[f"pos{pi}"] = {"k": ck, "v": cv}
-            else:
-                c = cache_slice[f"pos{pi}"]
-                mix, conv, state = S.ssm_decode(cfg, p["ssm"], hn,
-                                                c["conv"], c["state"])
-                new_slice[f"pos{pi}"] = {"conv": conv, "state": state}
-            x = x + mix
-            if pat.ffn != "none":
-                hn = L.apply_norm(cfg, p["norm2"], x)
-                if pat.ffn == "moe":
-                    ff, _ = L.moe_fwd(cfg, p["moe"], hn)
-                else:
-                    ff = L.mlp_fwd(cfg, p["mlp"], hn)
-                x = x + ff
-        return x, new_slice
+    def attn(p, hn, c):
+        mix, ck, cv = L.attention_decode(cfg, p, hn, c["k"], c["v"], pos)
+        return mix, {"k": ck, "v": cv}
 
-    h, new_cache = jax.lax.scan(scan_fn, h, (params["blocks"], cache),
-                                unroll=cfg.n_blocks if cfg.scan_unroll else 1)
+    def ssm(p, hn, c):
+        mix, conv, state = S.ssm_decode(cfg, p, hn, c["conv"], c["state"])
+        return mix, {"conv": conv, "state": state}
+
+    h, new_cache = _cached_layers(cfg, params, h, cache,
+                                  {"attn": attn, "ssm": ssm})
     return _head(cfg, params, h)[:, 0], new_cache

@@ -103,9 +103,6 @@ def make_rules(cfg, mesh: Mesh) -> ShardingRules:
         "ssm_state": None,
         "head_dim": None,
         "capacity": None,
-        # sequence-parallel TP (opt-in per config)
-        "seq_sp": (tp if (cfg is not None
-                          and getattr(cfg, "seq_parallel", False)) else None),
     }
     # Uneven-head attention (llama4 heads=40 on a 16-way model axis):
     # GSPMD partially replicates heads and all-reduces f32 score tensors

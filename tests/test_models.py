@@ -77,8 +77,8 @@ def test_smoke_train_step(arch):
     assert delta > 0
 
 
-@pytest.mark.parametrize("arch", ["qwen3-4b", "mamba2-2.7b",
-                                  "jamba-1.5-large-398b", "qwen2-vl-2b"])
+@pytest.mark.parametrize("arch", [a for a in ARCH_IDS
+                                  if not get_config(a).is_encdec])
 def test_decode_consistency(arch):
     cfg = get_config(arch, reduced=True)
     if cfg.n_experts:
@@ -118,9 +118,9 @@ def test_whisper_decode_consistency():
     assert max(errs) < 2e-3
 
 
-# (n_heads, n_kv_heads, kv_cache_repeat): query group 1 (MHA), 4, 6, and
-# a cache holding each KV head twice (4 cache heads, group 2)
-DECODE_HEADS = [(4, 4, 1), (8, 2, 1), (12, 2, 1), (8, 2, 2)]
+# (n_heads, n_kv_heads): query group 1 (MHA), 4, 6, and 8 over one cache
+# head (MQA)
+DECODE_HEADS = [(4, 4), (8, 2), (12, 2), (8, 1)]
 DECODE_TOL = {jnp.float32: 1e-5, jnp.bfloat16: 2e-2}
 MAX_LEN = 16
 
@@ -137,11 +137,10 @@ def test_attention_decode_matches_oracle(heads, cross, valid, dtype):
     path)."""
     from repro.kernels import ref
     from repro.models import layers as L
-    n_heads, n_kv, rep = heads
+    n_heads, Hc = heads
     cfg = dataclasses.replace(get_config("qwen3-4b", reduced=True),
-                              n_heads=n_heads, n_kv_heads=n_kv,
-                              kv_cache_repeat=rep)
-    B, Hc, D = 2, n_kv * rep, cfg.head_dim
+                              n_heads=n_heads, n_kv_heads=Hc)
+    B, D = 2, cfg.head_dim
     p, _ = L.init_attention(cfg, jax.random.PRNGKey(6))
     rng = np.random.default_rng(7)
     x = jnp.asarray(rng.standard_normal((B, 1, cfg.d_model)), dtype)
