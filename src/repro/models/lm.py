@@ -245,18 +245,50 @@ def cache_specs(cfg: ArchConfig) -> Tree:
 
 def _cached_layers(cfg: ArchConfig, params, h, cache, mixers):
     """The layer scan of prefill and decode: each block's layers read
-    their cache entries, and the scan stacks the new ones."""
-    def scan_fn(x, xs):
-        block_params, cache_slice = xs
+    their cache entries and return new ones.
+
+    An SSM position's entries (conv window, SSD state) are rewritten
+    whole every step, so they ride in the scan's carry: block i reads
+    its slice of the stack and writes the new one back at i, and XLA
+    updates the donated cache in place. Scanned as ``xs``/``ys``
+    instead, the new entries would be stacked in a temporary and the
+    stack copied into the output. An attention position's K/V entries
+    stay ``xs``/``ys``: a step adds one row to them, a different update;
+    a model with no SSM position carries nothing and scans no index."""
+    carried = {f"pos{pi}" for pi, pat in enumerate(cfg.pattern)
+               if pat.mixer == "ssm"}
+    stack = {k: c for k, c in cache.items() if k in carried}
+    xs = (params["blocks"], {k: c for k, c in cache.items()
+                             if k not in carried})
+    if carried:
+        xs += (jnp.arange(cfg.n_blocks),)
+
+    def scan_fn(carry, xs):
+        x, stack = carry
+        block_params, cache_slice, *index = xs
         new_slice = {}
         for pi, pat in enumerate(cfg.pattern):
-            x, _, new_slice[f"pos{pi}"] = _layer(
-                cfg, pat, block_params[f"pos{pi}"], x, mixers,
-                cache_slice[f"pos{pi}"])
-        return x, new_slice
+            key = f"pos{pi}"
+            if key in carried:
+                entry = jax.tree.map(
+                    lambda c: jax.lax.dynamic_index_in_dim(
+                        c, index[0], keepdims=False), stack[key])
+            else:
+                entry = cache_slice[key]
+            x, _, entry = _layer(cfg, pat, block_params[key], x, mixers,
+                                 entry)
+            if key in carried:
+                stack[key] = jax.tree.map(
+                    lambda c, e: jax.lax.dynamic_update_index_in_dim(
+                        c, e, index[0], 0), stack[key], entry)
+            else:
+                new_slice[key] = entry
+        return (x, stack), new_slice
 
-    return jax.lax.scan(scan_fn, h, (params["blocks"], cache),
-                        unroll=cfg.n_blocks if cfg.scan_unroll else 1)
+    (h, stack), new_cache = jax.lax.scan(
+        scan_fn, (h, stack), xs,
+        unroll=cfg.n_blocks if cfg.scan_unroll else 1)
+    return h, {**new_cache, **stack}
 
 
 def prefill(cfg: ArchConfig, params, tokens, max_len: int | None = None

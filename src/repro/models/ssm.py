@@ -22,7 +22,11 @@ term exp(cumsum a) ∘ C·Sᵀ. The prompt pads at its end to a whole chunk
 with x = b = c = 0 and a = 0, which changes neither the real positions
 nor the final state decode starts from. Operands are float32 and every
 contraction runs at HIGHEST precision, as in the token recurrence it
-replaces. Decode advances the state one token a step.
+replaces. Decode advances the state one token a step. Both entries are
+rewritten whole every step, so the layer scan (``lm._cached_layers``)
+carries every layer's stacked conv window and state and writes each
+layer's new entry back in place, in the donated cache; attention's K/V
+entries, which gain one row a step, stay the scan's inputs and outputs.
 """
 
 from __future__ import annotations

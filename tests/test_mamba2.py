@@ -140,6 +140,29 @@ def test_prefill_ssd_matches_the_token_recurrence(model, lengths):
         assert float(jnp.max(jnp.abs(g - w))) < TOL * scale
 
 
+def test_decode_writes_each_layers_cache_to_its_own_slot(model):
+    """Prefilling 5 tokens and decoding the other 7 one at a time leaves,
+    layer by layer, the SSD state and conv window that prefilling all 12
+    leaves, to float32 rounding: decode's in-place write of each layer's
+    entry into the carried stack lands at that layer."""
+    cfg, params, tokens = model
+    cfg = dataclasses.replace(cfg, n_layers=3)
+    params, _ = lm.init(cfg, jax.random.PRNGKey(4))
+    S, n_prompt = tokens.shape[1], 5
+    _, cache = lm.prefill(cfg, params, tokens[:, :n_prompt], max_len=S)
+    for t in range(n_prompt, S):
+        _, cache = lm.decode_step(cfg, params, cache, tokens[:, t:t + 1],
+                                  jnp.int32(t))
+    _, want = lm.prefill(cfg, params, tokens, max_len=S)
+    for name in ("state", "conv"):
+        got, ref_ = cache["pos0"][name], want["pos0"][name]
+        assert got.shape[0] == ref_.shape[0] == 3
+        for layer in range(3):
+            scale = float(jnp.max(jnp.abs(ref_[layer])))
+            err = float(jnp.max(jnp.abs(got[layer] - ref_[layer])))
+            assert err < TOL * scale, (name, layer, err, scale)
+
+
 def test_tied_head_has_no_leaf_of_its_own(model):
     cfg, params, _ = model
     assert "lm_head" not in params
